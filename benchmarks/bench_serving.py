@@ -43,6 +43,7 @@ import zlib
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import PageStore, bulk_load
 from repro.core.datasets import osm_like
 from repro.core.ioutil import atomic_write_json
@@ -356,6 +357,7 @@ def main(argv=None) -> int:
     ap.add_argument("--duration", type=float, default=None,
                     help="seconds per (mix, rate) run")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     n = args.n or (SMOKE_N if args.smoke else FULL_N)
     duration = args.duration or (1.5 if args.smoke else 5.0)

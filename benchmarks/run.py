@@ -9,6 +9,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro import compile_cache
+
 from . import (
     bench_adaptive,
     bench_construction,
@@ -33,6 +35,7 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", choices=sorted(SUITES))
     args = ap.parse_args(argv)
+    compile_cache.enable()
     todo = {args.only: SUITES[args.only]} if args.only else SUITES
     t0 = time.time()
     for name, fn in todo.items():

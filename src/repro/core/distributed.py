@@ -34,11 +34,6 @@ from .splittree import build_group_median_tree
 
 P = jax.sharding.PartitionSpec
 
-try:  # jax >= 0.5: top-level API
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 # --------------------------------------------------------------------------
 # 1. host-level m-server simulation (Figure 11)
@@ -249,9 +244,6 @@ def shard_build(points, mesh, levels_local: int, axis: str = "data",
         else:
             gs_dim = jnp.zeros((1, 1), jnp.int32)
             gs_val = jnp.zeros((1, 1), pts_local.dtype)
-            # derived from a device-varying value (not a closed-over
-            # constant): jax 0.4.x shard_map's replication check rejects
-            # sorting a pure constant on a 1-device mesh
             owner = ids_local * 0
         # --- fixed-capacity dispatch to owner shards ----------------------
         order = jnp.argsort(owner)
@@ -300,7 +292,7 @@ def shard_build(points, mesh, levels_local: int, axis: str = "data",
         )
         return out
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=P(axis, None),
         out_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
@@ -401,7 +393,7 @@ def shard_knn(shard_out, queries, k: int, mesh, levels_local: int,
         top_d2, sel_rows, sel_shard = gather_topk_merge(d2, rows, axis, k)
         return top_d2[None], sel_rows[None], sel_shard[None]
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis)),

@@ -64,11 +64,6 @@ from .queries_jax import (
 
 P = jax.sharding.PartitionSpec
 
-try:  # jax >= 0.5: top-level API
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 # --------------------------------------------------------------------------
 # degraded-mode protocol: shard outages + completeness certificates
@@ -556,6 +551,71 @@ def _check_mesh(stacked: dict, mesh, axis: str) -> np.ndarray:
     return lp
 
 
+STACKED_KEYS = ("leaf_pts", "leaf_ids", "leaf_counts", "leaf_lo", "leaf_hi")
+
+
+def place_stacked(stacked: dict, mesh, axis: str = "data") -> dict:
+    """Put each :meth:`ShardedDeviceTable.stacked` array on ``mesh`` with
+    its shard axis split over ``axis``: shard ``s`` goes straight to the
+    ``s``-th device and never passes through another one."""
+    _check_mesh(stacked, mesh, axis)
+    sharding = jax.sharding.NamedSharding(mesh, P(axis))
+    out = dict(stacked)
+    for key in STACKED_KEYS:
+        out[key] = jax.device_put(stacked[key], sharding)
+    return out
+
+
+def knn_shard_map_round(mesh, axis: str, k: int, n_leaves: int):
+    """The jit-able collective k-NN round of :func:`knn_batch_shard_map`:
+    ``fn(qs, leaf_pts, leaf_ids, leaf_counts, leaf_lo, leaf_hi)`` with
+    the queries replicated and the stacked arrays split over ``axis``."""
+
+    def body(qs, lp_l, li_l, lc_l, llo_l, lhi_l):
+        dev = DeviceTable(
+            leaf_pts=lp_l[0], leaf_ids=li_l[0], leaf_counts=lc_l[0],
+            leaf_lo=llo_l[0], leaf_hi=lhi_l[0], levels=(),
+        )
+        # full-budget local round: every leaf scanned, certificate trivial
+        ids, d2, _ = _knn_core(dev, qs, k, n_leaves, False)
+        top_d2, sel, _ = gather_topk_merge(d2, ids, axis, d2.shape[-1])
+        return top_d2[None], sel[None]
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(),) + (P(axis),) * len(STACKED_KEYS),
+        out_specs=(P(axis), P(axis)),
+    )
+
+
+def window_count_shard_map_round(mesh, axis: str):
+    """The jit-able collective counting round of
+    :func:`window_count_batch_shard_map`: ``fn(los, his, leaf_pts,
+    leaf_counts)`` with the windows replicated."""
+
+    def body(los, his, lp_l, lc_l):
+        pts = lp_l[0]                                     # (L, S, d)
+        s, d = pts.shape[1], pts.shape[2]
+        valid = (
+            jnp.arange(s, dtype=jnp.int32)[None, :] < lc_l[0][:, None]
+        )                                                  # (L, S)
+        # static unroll over dimensions: (Q, L, S) planes only, no
+        # (Q, L, S, d) broadcast temporaries (the frontier-test idiom)
+        inside = valid[None]
+        for j in range(d):
+            inside = inside & (
+                (pts[..., j][None] >= los[:, j][:, None, None])
+                & (pts[..., j][None] <= his[:, j][:, None, None])
+            )
+        local = jnp.sum(inside, axis=(1, 2)).astype(jnp.int32)
+        return jax.lax.psum(local, axis)[None]
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(P(), P(), P(axis), P(axis)),
+        out_specs=P(axis),
+    )
+
+
 def knn_batch_shard_map(
     stacked: dict,
     qs: np.ndarray,
@@ -565,44 +625,24 @@ def knn_batch_shard_map(
 ) -> tuple[np.ndarray, np.ndarray]:
     """Two-round k-NN as one compiled collective over a device mesh.
 
-    ``stacked`` is :meth:`ShardedDeviceTable.stacked`; the mesh's
-    ``axis`` plays the m local servers (its size must equal the shard
-    count).  Each device scans *all* of its shard's leaf blocks — the
-    local round is exact by construction — then the global round is an
-    ``all_gather`` of the per-shard (distance, id) top-k and one merge
-    top-k, exactly the ``shard_knn`` protocol but over the DeviceTable
-    layout with global ids (no local-slot translation).
+    ``stacked`` is :meth:`ShardedDeviceTable.stacked` (host arrays, or
+    already placed by :func:`place_stacked`); the mesh's ``axis`` plays
+    the m local servers (its size must equal the shard count).  Each
+    device scans *all* of its shard's leaf blocks — the local round is
+    exact by construction — then the global round is an ``all_gather``
+    of the per-shard (distance, id) top-k and one merge top-k, exactly
+    the ``shard_knn`` protocol but over the DeviceTable layout with
+    global ids (no local-slot translation).
 
     Returns ``(d2, ids)`` of shape (Q, k'), ascending per query, where
     ``k' = min(k, L*S)``; rows beyond a query's reachable points carry
     ``id = -1`` with +inf distance.
     """
     lp = _check_mesh(stacked, mesh, axis)
-    n_l = lp.shape[1]
-    n_total = int(stacked["n_points"])
+    placed = place_stacked(stacked, mesh, axis)
     qs_j = jnp.asarray(np.atleast_2d(np.asarray(qs, dtype=np.float32)))
-
-    def body(lp_l, li_l, lc_l, llo_l, lhi_l):
-        dev = DeviceTable(
-            leaf_pts=lp_l[0], leaf_ids=li_l[0], leaf_counts=lc_l[0],
-            leaf_lo=llo_l[0], leaf_hi=lhi_l[0], levels=(),
-            n_points=n_total,
-        )
-        # full-budget local round: every leaf scanned, certificate trivial
-        ids, d2, _ = _knn_core(dev, qs_j, k, n_l, False)
-        top_d2, sel, _ = gather_topk_merge(d2, ids, axis, d2.shape[-1])
-        return top_d2[None], sel[None]
-
-    fn = _shard_map(
-        body, mesh=mesh,
-        in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
-        out_specs=(P(axis), P(axis)),
-    )
-    d2, ids = fn(
-        jnp.asarray(lp), jnp.asarray(stacked["leaf_ids"]),
-        jnp.asarray(stacked["leaf_counts"]), jnp.asarray(stacked["leaf_lo"]),
-        jnp.asarray(stacked["leaf_hi"]),
-    )
+    fn = jax.jit(knn_shard_map_round(mesh, axis, k, lp.shape[1]))
+    d2, ids = fn(qs_j, *(placed[key] for key in STACKED_KEYS))
     # every shard holds the same merged answer; shard 0's copy suffices
     return np.asarray(d2[0]), np.asarray(ids[0])
 
@@ -622,29 +662,9 @@ def window_count_batch_shard_map(
     :func:`window_query_batch_sharded` stays the work-proportional
     collection engine — this is the mesh-resident counting round.
     """
-    lp = _check_mesh(stacked, mesh, axis)
+    placed = place_stacked(stacked, mesh, axis)
     los_j = jnp.asarray(np.atleast_2d(np.asarray(los, dtype=np.float32)))
     his_j = jnp.asarray(np.atleast_2d(np.asarray(his, dtype=np.float32)))
-    s, d = lp.shape[2], lp.shape[3]
-
-    def body(lp_l, lc_l):
-        pts = lp_l[0]                                     # (L, S, d)
-        valid = (
-            jnp.arange(s, dtype=jnp.int32)[None, :] < lc_l[0][:, None]
-        )                                                  # (L, S)
-        # static unroll over dimensions: (Q, L, S) planes only, no
-        # (Q, L, S, d) broadcast temporaries (the frontier-test idiom)
-        inside = valid[None]
-        for j in range(d):
-            inside = inside & (
-                (pts[..., j][None] >= los_j[:, j][:, None, None])
-                & (pts[..., j][None] <= his_j[:, j][:, None, None])
-            )
-        local = jnp.sum(inside, axis=(1, 2)).astype(jnp.int32)
-        return jax.lax.psum(local, axis)[None]
-
-    fn = _shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(axis),
-    )
-    counts = fn(jnp.asarray(lp), jnp.asarray(stacked["leaf_counts"]))
+    fn = jax.jit(window_count_shard_map_round(mesh, axis))
+    counts = fn(los_j, his_j, placed["leaf_pts"], placed["leaf_counts"])
     return np.asarray(counts[0])

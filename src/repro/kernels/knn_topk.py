@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .window_filter import PAIR_ROWS, _pad_pairs
+
 
 DEFAULT_QT = 256
 DEFAULT_PT = 512
@@ -125,17 +127,17 @@ def pairwise_dist2(
 # second-generation tiled kernels (fused traversal + scan; see ops.py)
 # --------------------------------------------------------------------------
 def _leaf_mindist_kernel(q_ref, lo_ref, hi_ref, out_ref):
-    q = q_ref[...]                          # (qt, d) float32
+    q = q_ref[...]                          # (d, qt) float32, dimension-major
     lo = lo_ref[...].astype(jnp.float32)    # (lt, d) bounds (f32 or bf16)
     hi = hi_ref[...].astype(jnp.float32)
-    acc = jnp.zeros((q.shape[0], lo.shape[0]), jnp.float32)
-    for k in range(q.shape[1]):             # static unroll over dimensions:
-        qk = q[:, k][:, None]               # one (qt, lt) plane at a time
-        g = jnp.maximum(lo[:, k][None, :] - qk, 0.0) + jnp.maximum(
-            qk - hi[:, k][None, :], 0.0
-        )
+    acc = jnp.zeros((lo.shape[0], q.shape[1]), jnp.float32)
+    for k in range(q.shape[0]):             # static unroll over dimensions:
+        qk = q[k:k + 1, :]                  # one (lt, qt) plane at a time,
+        g = jnp.maximum(lo[:, k:k + 1] - qk, 0.0) + jnp.maximum(
+            qk - hi[:, k:k + 1], 0.0        # built from 2-D slices: leaves
+        )                                   # on sublanes, queries on lanes
         acc = acc + g * g
-    out_ref[...] = acc
+    out_ref[...] = acc.T
 
 
 @functools.partial(jax.jit, static_argnames=("qt", "lt", "interpret"))
@@ -154,7 +156,11 @@ def leaf_mindist_tiled(
     may be bf16 (the compressed-MBB layout): outward rounding only widens a
     box, so a bf16 mindist never exceeds the f32 mindist — candidate
     selection stays a superset-safe underestimate and the exactness
-    certificate derived from it is conservative (see queries_jax)."""
+    certificate derived from it is conservative (see queries_jax).
+
+    The (nq, d) query batch enters dimension-major, (d, nq): Mosaic then
+    builds each (lt, qt) plane from a leaf column and a query row without
+    the 1-D relayouts whose scoped-VMEM stack grows with qt * lt."""
     nq, d = queries.shape
     n_l = leaf_lo.shape[0]
     assert nq % qt == 0 and n_l % lt == 0, "pad inputs to tile multiples"
@@ -163,29 +169,29 @@ def leaf_mindist_tiled(
         _leaf_mindist_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((qt, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((d, qt), lambda i, j: (0, i)),
             pl.BlockSpec((lt, d), lambda i, j: (j, 0)),
             pl.BlockSpec((lt, d), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((qt, lt), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nq, n_l), jnp.float32),
         interpret=interpret,
-    )(queries, leaf_lo, leaf_hi)
+    )(queries.T, leaf_lo, leaf_hi)
 
 
-def _pair_dist2_kernel(q_idx_ref, leaf_idx_ref, q_ref, pts_ref, cnt_ref,
-                       out_ref):
-    q = q_ref[...]                          # (1, d) this pair's query point
-    p = pts_ref[...]                        # (1, S, d) this pair's leaf block
-    cnt = cnt_ref[...]                      # (1,) live slots in the block
-    s = p.shape[1]
+def _pair_dist2_kernel(q_idx_ref, leaf_idx_ref, live_ref, q_ref,  # prefetch
+                       pts_ref, out_ref):
+    i = pl.program_id(0)
+    q = q_idx_ref[i]
+    s, d = pts_ref.shape[1], pts_ref.shape[2]
+    pt = pts_ref[0].T                       # (d, S): this pair's leaf block
     acc = jnp.zeros((1, s), jnp.float32)
-    for k in range(p.shape[2]):             # static unroll over dimensions
-        diff = p[..., k] - q[:, k][:, None]
+    for k in range(d):                      # static unroll over dimensions
+        diff = pt[k:k + 1, :] - q_ref[q * d + k]
         acc = acc + diff * diff
-    valid = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1) < cnt[:, None]
+    valid = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1) < live_ref[i]
     big = jnp.asarray(jnp.finfo(jnp.float32).max, jnp.float32)
-    out_ref[...] = jnp.where(valid, acc, big)
+    out_ref[pl.ds(i % PAIR_ROWS, 1), :] = jnp.where(valid, acc, big)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -203,23 +209,27 @@ def pair_dist2(
     Each pair's leaf block streams from the (L, S, d) table straight into
     VMEM through scalar-prefetch BlockSpec index maps — no XLA-materialized
     (P, S, d) gather.  Invalid slots carry float32 max so they sort last in
-    the top-k merge."""
+    the top-k merge.  The block layout follows
+    ``window_filter.pair_window_ids``: query points and per-pair live-slot
+    counts ride in SMEM, and each step writes one row of a
+    ``PAIR_ROWS``-row output tile."""
     n_p = q_idx.shape[0]
     _, s, d = leaf_pts.shape
+    q_idx, leaf_idx, live = _pad_pairs(
+        q_idx, leaf_idx, leaf_counts[leaf_idx]
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_p,),
+        num_scalar_prefetch=4,
+        grid=(q_idx.shape[0],),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, q, l: (q[i], 0)),
-            pl.BlockSpec((1, s, d), lambda i, q, l: (l[i], 0, 0)),
-            pl.BlockSpec((1,), lambda i, q, l: (l[i],)),
+            pl.BlockSpec((1, s, d), lambda i, q, l, n, a: (l[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, s), lambda i, q, l: (i, 0)),
+        out_specs=pl.BlockSpec((PAIR_ROWS, s),
+                               lambda i, q, l, n, a: (i // PAIR_ROWS, 0)),
     )
     return pl.pallas_call(
         _pair_dist2_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_p, s), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((q_idx.shape[0], s), jnp.float32),
         interpret=interpret,
-    )(q_idx.astype(jnp.int32), leaf_idx.astype(jnp.int32),
-      queries, leaf_pts, leaf_counts)
+    )(q_idx, leaf_idx, live, queries.reshape(-1), leaf_pts)[:n_p]

@@ -1,12 +1,10 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-``interpret`` defaults to auto-detection: Pallas executes the kernel body in
-Python on CPU (validation mode) and compiles to Mosaic on TPU.  All wrappers
-handle padding to tile multiples so callers can pass ragged sizes.
+``interpret`` defaults to the backend: Pallas interprets the kernel body on
+CPU (validation mode) and compiles to Mosaic on TPU.  All wrappers handle
+padding to tile multiples so callers can pass ragged sizes.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -18,20 +16,12 @@ from . import window_filter as _wf
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def interpret_default() -> bool:
-    """Resolve the interpret flag: the ``REPRO_PALLAS_INTERPRET`` env var
-    (1/0) wins — CI uses it to force interpret-mode kernel coverage on
-    CPU-only runners — else compile to Mosaic exactly when a TPU is
-    attached."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None and env != "":
-        return env not in ("0", "false", "False")
+    """Interpret exactly when no TPU is attached: on a TPU backend the
+    kernels always compile to Mosaic."""
     return not _on_tpu()
 
 

@@ -32,28 +32,44 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_QT = 128
 DEFAULT_PT = 512
 
-# VMEM budget for one tiled block's working set (inputs + output), well
-# under the ~16 MB/core so the pipeline can keep two blocks in flight
-VMEM_TILE_BUDGET = 4 * 1024 * 1024
+# VMEM budget for one tiled block's pipelined working set: half of the
+# 16 MiB scoped-VMEM limit Mosaic compiles kernels against, leaving the
+# rest for the compiler's own scratch
+VMEM_TILE_BUDGET = 8 * 1024 * 1024
+LANES = 128
+# (nt, qt) f32 planes a box-grid kernel body keeps live at once: the
+# accumulator plus the per-dimension comparison / gap temporaries
+LIVE_PLANES = 4
+# pairs per output tile of the pair-scan kernels: one f32/int32 sublane
+# tile, the smallest row block Mosaic accepts out of a larger array
+PAIR_ROWS = 8
+
+
+def tile_vmem_bytes(nt: int, qt: int, in_bytes: int = 4) -> int:
+    """VMEM one grid step of an (nt x qt) box-grid kernel occupies.
+
+    Every ``(rows, d)`` input tile fills whole 128-lane rows whatever d
+    is (a 2-wide minor axis pads 64x), the two bound tiles, the two query
+    tiles and the output plane are double-buffered by the pipeline, and
+    the body keeps ``LIVE_PLANES`` (nt, qt) f32 planes live."""
+    io = 2 * nt * LANES * in_bytes + 2 * qt * LANES * 4 + nt * qt * 4
+    return 2 * io + LIVE_PLANES * nt * qt * 4
 
 
 def vmem_tiles(n: int, q: int, d: int, in_bytes: int = 4,
                budget: int = VMEM_TILE_BUDGET) -> tuple[int, int]:
-    """(nt, qt) tile sizes for an (n x q) box-test grid whose per-block
-    working set — two (nt, d) bound tiles, two (qt, d) query tiles, and the
-    (nt, qt) output plane — fits ``budget`` bytes of VMEM.
+    """(nt, qt) tile sizes for an (n x q) box-test grid whose per-step
+    VMEM (:func:`tile_vmem_bytes`) fits ``budget`` bytes.
 
     Tiles respect the TPU minimums (8 sublanes x 128 lanes for f32; the
     bf16 bound tiles are cast to f32 in-register, so f32 minimums apply)
     and shrink the box axis first: the query axis is the broadcast axis,
-    so a wide qt amortizes bound loads across more queries."""
+    so a wide qt amortizes bound loads across more queries.  ``n`` and
+    ``d`` do not change the tile: the lane padding makes a tile's bytes
+    independent of d."""
     qt = min(128, _pow2_ceil(q))
     nt = 1024
-
-    def block_bytes(nt_, qt_):
-        return 2 * nt_ * d * in_bytes + 2 * qt_ * d * 4 + nt_ * qt_ * 4
-
-    while nt > 8 and block_bytes(nt, qt) > budget:
+    while nt > 8 and tile_vmem_bytes(nt, qt, in_bytes) > budget:
         nt //= 2
     return max(nt, 8), max(qt, 8)
 
@@ -263,36 +279,29 @@ def box_hits_tiled(
     )(lo, hi, qlo, qhi)
 
 
+def _pad_pairs(*cols):
+    """Pad per-pair index columns to a ``PAIR_ROWS`` multiple with
+    zeros; a padding pair gets zero live slots, so it matches nothing."""
+    n_p = cols[0].shape[0]
+    extra = -(-n_p // PAIR_ROWS) * PAIR_ROWS - n_p
+    return [jnp.pad(c.astype(jnp.int32), (0, extra)) for c in cols]
+
+
 def _pair_window_ids_kernel(
-    q_idx_ref, leaf_idx_ref, pv_ref,        # scalar prefetch (SMEM)
-    qlo_ref, qhi_ref, llo_ref, lhi_ref, pts_ref, ids_ref, cnt_ref,
-    out_ids_ref, out_cnt_ref,
+    q_idx_ref, leaf_idx_ref, live_ref, qlo_ref, qhi_ref,  # scalar prefetch
+    pts_ref, ids_ref, out_ref,
 ):
     i = pl.program_id(0)
-    qlo = qlo_ref[...]                      # (1, d) this pair's query box
-    qhi = qhi_ref[...]
-    llo = llo_ref[...].astype(jnp.float32)  # (1, d) exact f32 leaf MBB
-    lhi = lhi_ref[...].astype(jnp.float32)
-    p = pts_ref[...]                        # (1, S, d) this pair's leaf block
-    ids = ids_ref[...]                      # (1, S)
-    cnt = cnt_ref[...]                      # (1,) live slots in the block
-    s = p.shape[1]
-    valid = (
-        jax.lax.broadcasted_iota(jnp.int32, (1, s), 1) < cnt[:, None]
-    ) & (pv_ref[i] > 0)
-    # certified f32 re-check of the pair's leaf box: a pair surfaced by the
-    # widened bf16 frontier whose exact MBB misses the window is dropped
-    # here, before its slots can cost a containment test
-    box_ok = None
-    for k in range(p.shape[2]):
-        ok = (llo[:, k] <= qhi[:, k]) & (lhi[:, k] >= qlo[:, k])
-        box_ok = ok if box_ok is None else box_ok & ok
-    acc = valid & box_ok[:, None]
-    for k in range(p.shape[2]):             # exact containment on f32 points
-        pk = p[..., k]                      # (1, S)
-        acc = acc & (pk >= qlo[:, k][:, None]) & (pk <= qhi[:, k][:, None])
-    out_ids_ref[...] = jnp.where(acc, ids, -1)
-    out_cnt_ref[...] = jnp.sum(acc.astype(jnp.int32), axis=1)
+    q = q_idx_ref[i]
+    s, d = pts_ref.shape[1], pts_ref.shape[2]
+    pt = pts_ref[0].T                       # (d, S): this pair's leaf block
+    # the leaf's id row out of its PAIR_ROWS-row tile of the id table
+    ids = ids_ref[pl.ds(leaf_idx_ref[i] % PAIR_ROWS, 1), :]      # (1, S)
+    acc = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1) < live_ref[i]
+    for k in range(d):                      # exact containment on f32 points
+        pk = pt[k:k + 1, :]                 # (1, S)
+        acc = acc & (pk >= qlo_ref[q * d + k]) & (pk <= qhi_ref[q * d + k])
+    out_ref[pl.ds(i % PAIR_ROWS, 1), :] = jnp.where(acc, ids, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -315,39 +324,48 @@ def pair_window_ids(
     ``ids_or[p, s]`` is the dataset row of slot ``s`` of pair ``p``'s leaf
     when the point lies inside the pair's query window, else ``-1``; the
     device packing stage compacts the non-negatives.  The pair's leaf block
-    and id row are pulled straight from the (L, S, d) leaf table into VMEM
-    through scalar-prefetch BlockSpec index maps — the gather that the
+    is pulled straight from the (L, S, d) leaf table into VMEM through a
+    scalar-prefetch BlockSpec index map — the gather that the
     first-generation path materialized as an XLA (P, S, d) temporary is
-    fused into the kernel's block streaming."""
+    fused into the kernel's block streaming.
+
+    Mosaic only blocks the last two axes of an array in whole (8, 128)
+    tiles, so nothing here is a one-row block of a larger array: the
+    query boxes ride in SMEM with the other per-pair scalars, the id row
+    is read out of its ``PAIR_ROWS``-row tile, and each grid step writes
+    one row of a ``PAIR_ROWS``-row output tile that stays resident until
+    the pair index leaves it.  The certified f32 re-check of each pair's
+    exact leaf box (a pair surfaced by the widened bf16 frontier whose
+    exact MBB misses the window is dropped) and the padding mask fold into
+    the pair's live-slot count before the kernel runs."""
     n_p = q_idx.shape[0]
     _, s, d = leaf_pts.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_p,),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda i, q, l, pv: (q[i], 0)),
-            pl.BlockSpec((1, d), lambda i, q, l, pv: (q[i], 0)),
-            pl.BlockSpec((1, d), lambda i, q, l, pv: (l[i], 0)),
-            pl.BlockSpec((1, d), lambda i, q, l, pv: (l[i], 0)),
-            pl.BlockSpec((1, s, d), lambda i, q, l, pv: (l[i], 0, 0)),
-            pl.BlockSpec((1, s), lambda i, q, l, pv: (l[i], 0)),
-            pl.BlockSpec((1,), lambda i, q, l, pv: (l[i],)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, s), lambda i, q, l, pv: (i, 0)),
-            pl.BlockSpec((1,), lambda i, q, l, pv: (i,)),
-        ],
+    box_ok = jnp.all(
+        (leaf_lo[leaf_idx].astype(jnp.float32) <= qhi[q_idx])
+        & (leaf_hi[leaf_idx].astype(jnp.float32) >= qlo[q_idx]),
+        axis=1,
     )
-    return pl.pallas_call(
+    live = jnp.where(box_ok & (pair_valid > 0), leaf_counts[leaf_idx], 0)
+    q_idx, leaf_idx, live = _pad_pairs(q_idx, leaf_idx, live)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(q_idx.shape[0],),
+        in_specs=[
+            pl.BlockSpec((1, s, d), lambda i, q, l, n, a, b: (l[i], 0, 0)),
+            pl.BlockSpec((PAIR_ROWS, s),
+                         lambda i, q, l, n, a, b: (l[i] // PAIR_ROWS, 0)),
+        ],
+        out_specs=pl.BlockSpec((PAIR_ROWS, s),
+                               lambda i, q, l, n, a, b: (i // PAIR_ROWS, 0)),
+    )
+    ids_or = pl.pallas_call(
         _pair_window_ids_kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_p, s), jnp.int32),
-            jax.ShapeDtypeStruct((n_p,), jnp.int32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((q_idx.shape[0], s), jnp.int32),
         interpret=interpret,
     )(
-        q_idx.astype(jnp.int32), leaf_idx.astype(jnp.int32),
-        pair_valid.astype(jnp.int32),
-        qlo, qhi, leaf_lo, leaf_hi, leaf_pts, leaf_ids, leaf_counts,
-    )
+        q_idx, leaf_idx, live, qlo.reshape(-1), qhi.reshape(-1),
+        leaf_pts, leaf_ids,
+    )[:n_p]
+    # every live slot carries a non-negative dataset row
+    return ids_or, jnp.sum((ids_or >= 0).astype(jnp.int32), axis=1)

@@ -30,7 +30,7 @@ from ..models import model as M
 from ..models.sharding import axes_for_mesh
 from ..train import optimizer as opt_mod
 from ..train.trainer import make_train_step, pick_microbatches
-from .mesh import make_production_mesh, use_mesh
+from .mesh import make_production_mesh
 
 RESULTS_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments"
 
@@ -62,7 +62,7 @@ def lower_cell(cfg, shape, mesh, *, probe_blocks: int | None = None,
     for a in axes.dp:
         n_dp *= mesh.shape[a]
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_name = opt_mod.pick_for(cfg)
             optimizer = opt_mod.get_optimizer(opt_name)
@@ -117,7 +117,7 @@ def run_cell(cfg, shape, mesh, *, probes: bool = False,
                                          extra_cfg=extra_cfg,
                                          force_micro=force_micro)
     ma = compiled.memory_analysis()
-    ca = roofline.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     rec = {
         "arch": cfg.name,
         "shape": shape.name,
@@ -146,7 +146,7 @@ def run_cell(cfg, shape, mesh, *, probes: bool = False,
         for nb in (1, 2):
             _, c, _ = lower_cell(cfg, shape, mesh, probe_blocks=nb,
                                  extra_cfg=extra_cfg)
-            pca = roofline.cost_analysis_dict(c)
+            pca = c.cost_analysis()
             pc = roofline.parse_collectives(c.as_text())
             probe[f"blocks{nb}"] = {
                 "flops": pca.get("flops", 0.0),

@@ -10,27 +10,12 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across the 0.4 -> 0.7 API drift: newer jax wants
-    explicit ``axis_types`` (Auto keeps the legacy sharding semantics),
-    jax 0.4 has no such kwarg (Auto is the only behaviour)."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (AttributeError, TypeError):  # jax 0.4: no AxisType/axis_types
-        return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` where it
-    exists (jax >= 0.6), the ``Mesh`` object's own context manager (which
-    sets the thread-resident mesh ``with_sharding_constraint`` resolves
-    PartitionSpecs against) on jax 0.4."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` with every axis ``Auto``: shardings propagate
+    through ``with_sharding_constraint`` hints, the semantics the model
+    code is written for (``jax.make_mesh`` defaults to ``Explicit``)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

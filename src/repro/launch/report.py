@@ -12,6 +12,8 @@ from ..configs.base import SHAPES, get_config
 
 DRYRUN = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 CHIPS = {"16x16": 256, "2x16x16": 512}
+# the production meshes the dry run lowers for are v5e pods
+TARGET_KIND = "TPU v5 lite"
 
 
 def load_cells() -> list[dict]:
@@ -38,7 +40,7 @@ def roofline_row(rec: dict) -> dict:
         # degenerate; fall back to the analytic traffic model
         hbm = roofline.analytic_hbm_bytes(cfg, shape, n_micro)
     coll = rec["collectives"]["total_bytes"]
-    terms = roofline.roofline_terms(flops, hbm, coll, chips)
+    terms = roofline.roofline_terms(flops, hbm, coll, chips, TARGET_KIND)
     model_flops = ana["model_flops_6nd"]
     return {
         "arch": rec["arch"],

@@ -14,7 +14,7 @@ import numpy as np
 from ..configs.base import all_configs, get_config
 from ..models import model as M
 from ..serve.engine import LMServer
-from .mesh import make_host_mesh, use_mesh
+from .mesh import make_host_mesh
 from .train import reduced_config
 
 
@@ -37,7 +37,7 @@ def main(argv=None):
             "enc-dec and vlm flows"
         )
     mesh = make_host_mesh()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = M.init_params(cfg, jax.random.key(0))
         server = LMServer(cfg, params)
         rng = np.random.default_rng(0)

@@ -25,7 +25,7 @@ from ..models.sharding import axes_for_mesh
 from ..train import optimizer as opt_mod
 from ..train.checkpoint import CheckpointManager
 from ..train.trainer import make_train_step
-from .mesh import make_host_mesh, use_mesh
+from .mesh import make_host_mesh
 
 
 def reduced_config(cfg, *, layers=2, d_model=128, vocab=512):
@@ -103,7 +103,7 @@ def main(argv=None):
     mgr = CheckpointManager(args.ckpt_dir)
     start = 0
     restored, extra = mgr.restore()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if restored is not None:
             print(f"restored step {extra['step']}")
             params = restored["params"]

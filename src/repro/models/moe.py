@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .layers import Param, swiglu
-from .sharding import ambient_mesh, shard_map_compat
 
 TOKEN_CHUNK = 8192
 
@@ -87,14 +86,11 @@ def moe_ffn(p, cfg, x, axes):
     dp = axes.dp if len(axes.dp) > 1 else axes.dp[0]
     tp = axes.tp
 
-    mesh = ambient_mesh()
-    try:
-        n_dp = 1
-        for a in (axes.dp if isinstance(axes.dp, tuple) else (axes.dp,)):
-            n_dp *= mesh.shape[a]
-    except Exception:
-        mesh, n_dp = None, 1
-    batch_spec = dp if (mesh is not None and B % max(n_dp, 1) == 0) else None
+    mesh = jax.sharding.get_abstract_mesh()
+    n_dp = 1
+    for a in (axes.dp if isinstance(axes.dp, tuple) else (axes.dp,)):
+        n_dp *= mesh.shape[a]
+    batch_spec = dp if B % n_dp == 0 else None
 
     def body(router, w1, w3, w2, xt):
         E_l = w1.shape[0]
@@ -137,9 +133,9 @@ def moe_ffn(p, cfg, x, axes):
         out = jax.lax.psum(out, tp)  # EP combine across expert shards
         return out.reshape(Bl, Sl, D)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(
             P(None, None),        # router: replicated
             P(tp, None, None),    # experts sharded over the model axis

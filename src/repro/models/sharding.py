@@ -42,40 +42,6 @@ class MeshAxes:
         return P(self.dp if len(self.dp) > 1 else self.dp[0])
 
 
-def ambient_mesh():
-    """The mesh the caller activated, across the jax 0.4 -> 0.7 API drift:
-    ``jax.sharding.get_abstract_mesh()`` under ``jax.set_mesh``, the
-    thread-resident physical mesh under the jax-0.4 ``with mesh:`` context.
-    Returns None when no mesh is active."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except AttributeError:
-        pass
-    try:
-        from jax._src.mesh import thread_resources
-
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` where it exists; the experimental namespace (with
-    the replication check off — jax 0.4's checker rejects valid psum
-    patterns) otherwise.  ``mesh`` must be the active mesh."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as sm_old
-
-    return sm_old(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 def axes_for_mesh(mesh) -> MeshAxes:
     names = mesh.axis_names
     dp = tuple(n for n in names if n in ("pod", "data"))

@@ -1,10 +1,11 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e constants):
+Three terms per (arch x shape x mesh), in seconds, against the published
+peaks of the chip named by its ``device_kind`` (:data:`CHIP_PEAKS`):
 
-  compute_s    = FLOPs / (chips * 197e12 bf16 FLOP/s)
-  memory_s     = HBM bytes / (chips * 819e9 B/s)
-  collective_s = collective bytes / (chips * 50e9 B/s per ICI link)
+  compute_s    = FLOPs / (chips * peak bf16 FLOP/s)
+  memory_s     = HBM bytes / (chips * HBM bytes/s)
+  collective_s = collective bytes / (chips * ICI bytes/s per link)
 
 Sources:
   * ``parse_collectives`` extracts every all-gather / all-reduce /
@@ -26,11 +27,33 @@ from __future__ import annotations
 import re
 from collections import defaultdict
 
-# --- TPU v5e hardware constants ------------------------------------------
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# --- published chip peaks, keyed by jax ``Device.device_kind`` -----------
+# TPU v5e: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 394 TOP/s int8, 16 GiB HBM at 819 GB/s, and
+# 1,600 Gbit/s of inter-chip interconnect over four links (50 GB/s each).
+CHIP_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 394e12,
+        "hbm_bytes": 16 * 1024 ** 3,
+        "hbm_bw": 819e9,
+        "ici_bw_per_link": 50e9,
+    },
+}
 MXU_MIN_DIM = 128
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Published peaks of the chip ``device_kind`` names (as
+    ``jax.devices()[0].device_kind`` reports it).  A chip that is not in
+    :data:`CHIP_PEAKS` is an error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; "
+            f"known: {sorted(CHIP_PEAKS)}"
+        ) from None
 
 COLLECTIVES = (
     "all-gather", "all-reduce", "reduce-scatter", "all-to-all",
@@ -43,15 +66,6 @@ _DTYPE_BYTES = {
     "f8e4m3fn": 1, "f8e5m2": 1, "s4": 1, "u4": 1,
 }
 
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` across the jax 0.4 -> 0.7 drift: older
-    jax returns a per-device list of dicts, newer jax one dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
 
 def _shape_bytes(type_str: str) -> int:
     """Bytes of an HLO type string, e.g. 'bf16[2,1024,512]{2,1,0}' or a
@@ -373,15 +387,13 @@ def bytes_pair_dist2(p: int, s: int, d: int) -> int:
 
 
 def kernel_roofline(bytes_moved: float, seconds: float,
-                    bw: float = HBM_BW) -> dict:
-    """Achieved-vs-peak bandwidth for one kernel invocation.
-
-    ``bw`` defaults to the TPU v5e HBM roof; pass a host-measured STREAM
-    number when the wall-clock came from the CPU backend (interpret-mode
-    Pallas timings are *not* meaningful inputs — measure the compiled
-    path)."""
+                    device_kind: str) -> dict:
+    """Achieved-vs-peak HBM bandwidth for one kernel invocation timed on
+    the chip ``device_kind`` names (a CPU or interpret-mode timing has no
+    roof here: it raises)."""
     if seconds <= 0:
         raise ValueError("seconds must be positive")
+    bw = chip_peaks(device_kind)["hbm_bw"]
     achieved = bytes_moved / seconds
     return {
         "bytes": float(bytes_moved),
@@ -393,10 +405,11 @@ def kernel_roofline(bytes_moved: float, seconds: float,
 
 
 def roofline_terms(flops: float, hbm_bytes: float, coll_bytes: float,
-                   chips: int) -> dict:
-    compute_s = flops / (chips * PEAK_FLOPS)
-    memory_s = hbm_bytes / (chips * HBM_BW)
-    collective_s = coll_bytes / (chips * ICI_BW)
+                   chips: int, device_kind: str) -> dict:
+    peaks = chip_peaks(device_kind)
+    compute_s = flops / (chips * peaks["bf16_flops"])
+    memory_s = hbm_bytes / (chips * peaks["hbm_bw"])
+    collective_s = coll_bytes / (chips * peaks["ici_bw_per_link"])
     terms = {
         "compute_s": compute_s,
         "memory_s": memory_s,

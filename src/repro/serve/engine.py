@@ -246,7 +246,11 @@ class DeviceQueryServer:
         import os
 
         from ..core.distributed_jax import ShardedDeviceTable
-        from ..core.queries_jax import DeviceTable, UploadStats
+        from ..core.queries_jax import (
+            DeviceTable,
+            UploadStats,
+            _use_kernel_default,
+        )
         from .journal import GraftJournal
         from .resilience import RetryPolicy, TableLock
 
@@ -327,7 +331,11 @@ class DeviceQueryServer:
         self._stream_device_stale = False
         self.compact_slack = float(compact_slack)
         self.microbatch = int(microbatch)
-        self.use_kernel = use_kernel
+        # resolved once, from the attached backend: the Pallas kernels on a
+        # TPU, the equivalent jnp path elsewhere
+        self.use_kernel = (
+            _use_kernel_default() if use_kernel is None else bool(use_kernel)
+        )
         self.compressed = bool(compressed)
         self.stats = DeviceQueryStats(shards=n_shards)
         # durability plane (adaptive only): write-ahead graft journal +

@@ -8,7 +8,7 @@ import pytest
 
 from repro.configs.base import all_configs
 from repro.launch.train import reduced_config
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models.sharding import MeshAxes
 
@@ -44,7 +44,7 @@ def test_reduced_forward_and_train_step(arch, mesh):
     batch = _batch(cfg, rng)
     params = M.init_params(cfg, jax.random.key(0), jnp.float32)
     axes = MeshAxes()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lg, _ = M.forward(params, cfg, batch, axes, mode="train")
         loss, grads = jax.value_and_grad(M.loss_fn)(params, cfg, batch, axes)
     seq = batch["tokens"].shape[1] + (
@@ -65,7 +65,7 @@ def test_reduced_one_sgd_step_changes_params(arch, mesh):
     batch = _batch(cfg, rng)
     params = M.init_params(cfg, jax.random.key(1), jnp.float32)
     axes = MeshAxes()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         grads = jax.grad(M.loss_fn)(params, cfg, batch, axes)
         new = jax.tree.map(lambda p, g: p - 1e-3 * g, params, grads)
         delta = sum(

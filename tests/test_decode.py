@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ModelConfig
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models.sharding import MeshAxes
 
@@ -35,7 +35,7 @@ def _check(cfg, mesh, tol=2e-3):
     toks = jnp.asarray(rng.integers(0, cfg.vocab, (B, S)), jnp.int32)
     params = M.init_params(cfg, jax.random.key(1), jnp.float32)
     axes = MeshAxes()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lg_full, _ = M.forward(params, cfg, {"tokens": toks}, axes,
                                mode="train")
         s0 = S - TAIL
@@ -96,7 +96,7 @@ def test_encdec_decode_with_cross_cache(mesh):
     dec = jnp.asarray(rng.integers(0, 100, (B, 16)), jnp.int32)
     params = M.init_params(cfg, jax.random.key(2), jnp.float32)
     axes = MeshAxes()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lg_full, _ = M.forward(
             params, cfg, {"frames": frames, "tokens": dec}, axes,
             mode="train",

@@ -291,11 +291,8 @@ from repro.core.distributed_jax import (
     window_count_batch_shard_map,
 )
 from repro.core.queries_jax import DeviceTable, knn_query_batch_jax
-try:
-    mesh = jax.make_mesh((4,), ("data",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-except (AttributeError, TypeError):
-    mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 pts = rng.random((8192, 2)).astype(np.float32).astype(np.float64)
 idx = bulk_load(pts, 250, PageStore(250))

@@ -131,11 +131,8 @@ from repro.core import distributed
 from repro.core.datasets import gaussian
 if len(jax.devices()) < 8:
     print(f"DIST-SKIP: only {len(jax.devices())} devices"); sys.exit(0)
-try:
-    mesh = jax.make_mesh((8,), ("data",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-except (AttributeError, TypeError):  # older jax: no axis_types kwarg
-    mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 pts = gaussian(8192, 2, seed=5).astype(np.float32)
 out = distributed.shard_build(jnp.asarray(pts), mesh, levels_local=4)
 nm = np.asarray(out[6]).ravel()

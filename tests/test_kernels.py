@@ -289,11 +289,21 @@ def test_box_hits_tiled_compiled_matches_interpret():
 
 
 def test_vmem_tiles_respect_budget():
-    from repro.kernels.window_filter import VMEM_TILE_BUDGET, vmem_tiles
+    from repro.kernels.window_filter import (
+        LANES,
+        VMEM_TILE_BUDGET,
+        tile_vmem_bytes,
+        vmem_tiles,
+    )
 
     for n, q, d, b in [(100_000, 64, 2, 4), (5000, 1024, 16, 4),
                        (128, 8, 3, 2)]:
         nt, qt = vmem_tiles(n, q, d, in_bytes=b)
         assert nt >= 8 and qt >= 8
-        block = 2 * nt * d * b + 2 * qt * d * 4 + nt * qt * 4
-        assert block <= VMEM_TILE_BUDGET or (nt, qt) == (8, 8)
+        assert tile_vmem_bytes(nt, qt, b) <= VMEM_TILE_BUDGET \
+            or (nt, qt) == (8, 8)
+        # a (rows, d) tile is charged whole 128-lane rows whatever d is,
+        # double-buffered, for both bound columns
+        assert tile_vmem_bytes(nt, qt, b) >= 2 * 2 * nt * LANES * b
+    # the budget stays inside the 16 MiB scoped-VMEM limit
+    assert VMEM_TILE_BUDGET <= 16 * 1024 * 1024

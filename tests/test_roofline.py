@@ -10,7 +10,7 @@ import pytest
 from repro import roofline
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import model as M
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.sharding import MeshAxes
 
 
@@ -33,7 +33,7 @@ import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro import roofline
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((4,), ("m",))
 L, D = 7, 64
 def f(ws, x):
@@ -46,7 +46,7 @@ ws = jax.ShapeDtypeStruct((L, D, D), jnp.float32,
                           sharding=NamedSharding(mesh, P(None, "m", None)))
 x = jax.ShapeDtypeStruct((8, D), jnp.float32,
                          sharding=NamedSharding(mesh, P(None, "m")))
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     c = jax.jit(f).lower(ws, x).compile()
 res = roofline.parse_collectives(c.as_text())
 counts = sum(res["counts"].values())
@@ -83,20 +83,20 @@ def test_analytic_flops_close_to_xla_forward():
     axes = MeshAxes()
     params = M.abstract_params(cfg, mesh, jnp.float32)
     inputs = M.input_specs(cfg, shape, mesh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         c = jax.jit(lambda p, b: M.prefill(p, cfg, b, axes)).lower(
             params, inputs
         ).compile()
-    xla = roofline.cost_analysis_dict(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     # scan over 2 layers counted once by XLA -> add one body back
     body = xla  # lower 1-layer variant for the body estimate
     cfg1 = dataclasses.replace(cfg, n_layers=1)
     params1 = M.abstract_params(cfg1, mesh, jnp.float32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         c1 = jax.jit(lambda p, b: M.prefill(p, cfg1, b, axes)).lower(
             params1, inputs
         ).compile()
-    xla1 = roofline.cost_analysis_dict(c1)["flops"]
+    xla1 = c1.cost_analysis()["flops"]
     per_layer = xla - xla1 if xla > xla1 else 0.0
     xla_full = xla1 + per_layer * cfg.n_layers  # body-once corrected
     ana = roofline.analytic_flops(cfg, shape)["fwd_flops"]
@@ -107,14 +107,35 @@ def test_analytic_flops_close_to_xla_forward():
 
 def test_roofline_terms_and_dominance():
     t = roofline.roofline_terms(
-        flops=1e15, hbm_bytes=1e12, coll_bytes=1e11, chips=256
+        flops=1e15, hbm_bytes=1e12, coll_bytes=1e11, chips=256,
+        device_kind="TPU v5 lite",
     )
     assert t["dominant"] == "compute_s"
     assert 0 < t["roofline_fraction"] <= 1.0
     t2 = roofline.roofline_terms(
-        flops=1e12, hbm_bytes=1e14, coll_bytes=1e11, chips=256
+        flops=1e12, hbm_bytes=1e14, coll_bytes=1e11, chips=256,
+        device_kind="TPU v5 lite",
     )
     assert t2["dominant"] == "memory_s"
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    v5e = roofline.chip_peaks("TPU v5 lite")
+    assert v5e["bf16_flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    r = roofline.kernel_roofline(819e9, 2.0, "TPU v5 lite")
+    assert r["peak_fraction"] == pytest.approx(0.5)
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v5", ""])
+def test_chip_peaks_unknown_device_kind_raises(kind):
+    """A chip with no published peaks in the table is an error, not a
+    silent fallback to another chip's roof."""
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.chip_peaks(kind)
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.kernel_roofline(1e9, 1.0, kind)
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.roofline_terms(1e12, 1e12, 0.0, 1, kind)
 
 
 def test_probe_extrapolation_linear():

@@ -6,7 +6,7 @@ import pytest
 from repro.configs.base import ModelConfig
 from repro.core.datasets import osm_like
 from repro.launch.train import reduced_config
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.serve.engine import LMServer, RetrievalServer
 
@@ -17,7 +17,7 @@ def test_lm_server_greedy_generation():
         name="t", family="dense", n_layers=2, d_model=64, n_heads=4,
         n_kv_heads=2, d_ff=128, vocab=100, dtype="float32", chunk_q=16,
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = M.init_params(cfg, jax.random.key(0))
         server = LMServer(cfg, params)
         prompts = np.random.default_rng(0).integers(0, 100, (2, 12))
