@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from .jax_index import _pow2
 from .nodetable import NodeTable
 
@@ -75,21 +76,6 @@ BIG = float(np.finfo(np.float32).max)
 # candidate sets stream in chunks so memory stays bounded and compiled
 # variants stay the handful of power-of-two bucket sizes below the cap
 PAIR_CHUNK = 16384
-
-# retrace counters (trace-time side effects): tests pin compile growth
-TRACE_COUNTS = {
-    "frontier": 0,
-    "window_collect": 0,
-    "knn_core": 0,
-    "pair_pack": 0,   # on-device (query, leaf) pair compaction chunks
-    "id_pack": 0,     # on-device qualifying-id compaction buckets
-    "knn_sel": 0,     # on-device pending-query gathers (budget escalation)
-}
-
-
-def trace_counts() -> dict:
-    """Snapshot of the retrace counters (a copy, safe to diff against)."""
-    return dict(TRACE_COUNTS)
 
 # host -> device upload accounting: the adaptive-serving tests prove a graft
 # refreshes the device table by uploading only its delta (full_exports stays
@@ -477,7 +463,6 @@ def frontier_leaf_hits(
     Branch rows scatter into the sentinel row ``L + U`` of the
     accumulator, which is dropped.
     """
-    TRACE_COUNTS["frontier"] += 1
     q = los.shape[0]
     n_slots = dev.n_leaves + dev.n_cold
     d = dev.dim
@@ -528,33 +513,33 @@ def _frontier_count(
     :func:`frontier_leaf_hits`.  A compressed export is traversed against
     its bf16 bounds — the resulting superset costs only extra candidate
     pairs, which the exact-f32 collection stage rejects."""
-    TRACE_COUNTS["frontier"] += 1
-    q = los.shape[0]
-    n_slots = dev.n_leaves + dev.n_cold
-    d = dev.dim
-    leaf_hit = jnp.zeros((n_slots + 1, q), dtype=bool)
-    prev = None
-    for i, (_, _, parent, slot) in enumerate(dev.levels):
-        lo_l, hi_l = _level_bounds(dev, i)
-        if use_kernel:
-            from ..kernels import ops as kops
+    with jax.named_scope("frontier"):
+        q = los.shape[0]
+        n_slots = dev.n_leaves + dev.n_cold
+        d = dev.dim
+        leaf_hit = jnp.zeros((n_slots + 1, q), dtype=bool)
+        prev = None
+        for i, (_, _, parent, slot) in enumerate(dev.levels):
+            lo_l, hi_l = _level_bounds(dev, i)
+            if use_kernel:
+                from ..kernels import ops as kops
 
-            hit = kops.box_hits_tiled(lo_l, hi_l, los, his) > 0
-        else:
-            hit = None
-            for j in range(d):
-                h = (
-                    lo_l[:, j].astype(jnp.float32)[:, None] <= his[:, j][None, :]
-                ) & (
-                    hi_l[:, j].astype(jnp.float32)[:, None] >= los[:, j][None, :]
-                )
-                hit = h if hit is None else hit & h
-        if prev is not None:
-            hit = hit & prev[parent]
-        leaf_hit = leaf_hit.at[slot].max(hit)
-        prev = hit
-    hits = leaf_hit[:n_slots].T
-    n_pairs = jnp.sum(hits[:, : dev.n_leaves].astype(jnp.int32))
+                hit = kops.box_hits_tiled(lo_l, hi_l, los, his) > 0
+            else:
+                hit = None
+                for j in range(d):
+                    lo_j = lo_l[:, j].astype(jnp.float32)[:, None]
+                    hi_j = hi_l[:, j].astype(jnp.float32)[:, None]
+                    h = (lo_j <= his[:, j][None, :]) & (
+                        hi_j >= los[:, j][None, :]
+                    )
+                    hit = h if hit is None else hit & h
+            if prev is not None:
+                hit = hit & prev[parent]
+            leaf_hit = leaf_hit.at[slot].max(hit)
+            prev = hit
+        hits = leaf_hit[:n_slots].T
+        n_pairs = jnp.sum(hits[:, : dev.n_leaves].astype(jnp.int32))
     return hits, n_pairs
 
 
@@ -597,31 +582,32 @@ def _fused_pack_scan(
     this is the certified re-check that keeps a compressed traversal
     id-identical.  Returns the (pc, S) ids-or-minus-one matrix, per-query
     qualifying counts, and the chunk's id total."""
-    TRACE_COUNTS["pair_pack"] += 1
-    TRACE_COUNTS["window_collect"] += 1
-    flat = hits[:, : dev.n_leaves].reshape(-1)
-    pos, ranks, n_pairs = _compact_idx(flat, 1, pc, offset)
-    pair_valid = (ranks <= n_pairs).astype(jnp.int32)
-    q_idx = pos // dev.n_leaves
-    leaf_idx = pos % dev.n_leaves
-    if use_kernel:
-        from ..kernels import ops as kops
+    with jax.named_scope("pair_compaction"):
+        flat = hits[:, : dev.n_leaves].reshape(-1)
+        pos, ranks, n_pairs = _compact_idx(flat, 1, pc, offset)
+        pair_valid = (ranks <= n_pairs).astype(jnp.int32)
+        q_idx = pos // dev.n_leaves
+        leaf_idx = pos % dev.n_leaves
+    with jax.named_scope("scan"):
+        if use_kernel:
+            from ..kernels import ops as kops
 
-        ids_or, pair_counts = kops.pair_window_ids(
-            los, his, dev.leaf_lo, dev.leaf_hi, dev.leaf_pts, dev.leaf_ids,
-            dev.leaf_counts, q_idx, leaf_idx, pair_valid,
-        )
-    else:
-        from ..kernels import ref as kref
+            ids_or, pair_counts = kops.pair_window_ids(
+                los, his, dev.leaf_lo, dev.leaf_hi, dev.leaf_pts,
+                dev.leaf_ids, dev.leaf_counts, q_idx, leaf_idx, pair_valid,
+            )
+        else:
+            from ..kernels import ref as kref
 
-        ids_or, pair_counts = kref.pair_window_ids_ref(
-            los, his, dev.leaf_lo, dev.leaf_hi, dev.leaf_pts, dev.leaf_ids,
-            dev.leaf_counts, q_idx, leaf_idx, pair_valid,
+            ids_or, pair_counts = kref.pair_window_ids_ref(
+                los, his, dev.leaf_lo, dev.leaf_hi, dev.leaf_pts,
+                dev.leaf_ids, dev.leaf_counts, q_idx, leaf_idx, pair_valid,
+            )
+    with jax.named_scope("per_query_sum"):
+        per_query = jax.ops.segment_sum(
+            pair_counts, q_idx, num_segments=los.shape[0]
         )
-    per_query = jax.ops.segment_sum(
-        pair_counts, q_idx, num_segments=los.shape[0]
-    )
-    return ids_or, per_query, jnp.sum(pair_counts)
+        return ids_or, per_query, jnp.sum(pair_counts)
 
 
 @functools.partial(jax.jit, static_argnames=("r",))
@@ -632,10 +618,10 @@ def _fused_id_pack(ids_or: jnp.ndarray, r: int):
     Used when compiled kernels are available (TPU), where shipping the
     packed ids beats shipping the (P, S) matrix; the CPU path extracts on
     the host instead (transfer is cheap there, device compaction is not)."""
-    TRACE_COUNTS["id_pack"] += 1
-    flat = ids_or.reshape(-1)
-    pos, ranks, total = _compact_idx(flat >= 0, 1, r, jnp.int32(0))
-    return jnp.where(ranks <= total, flat[pos], -1)
+    with jax.named_scope("id_pack"):
+        flat = ids_or.reshape(-1)
+        pos, ranks, total = _compact_idx(flat >= 0, 1, r, jnp.int32(0))
+        return jnp.where(ranks <= total, flat[pos], -1)
 
 
 def _window_batch_fused(
@@ -653,43 +639,63 @@ def _window_batch_fused(
     pick the pair bucket.  ``device_id_pack`` (default: only where
     compiled kernels run) additionally compacts the qualifying ids on
     device so the transfer is work-proportional; on CPU the (P, S) matrix
-    transfer + NumPy extraction is faster than any XLA compaction."""
+    transfer + NumPy extraction is faster than any XLA compaction.
+
+    Traced as one ``query.window`` span with the batch's real queries
+    ``q``, intersecting pairs ``pairs`` against the pair bucket slots
+    ``pair_slots`` over ``chunks``, and qualifying ``ids`` against the id
+    pack's slots ``id_slots``; each blocking device-to-host read is a
+    ``query.sync`` span (``what`` names it), the final split ``query.split``."""
     if device_id_pack is None:
         from ..kernels import ops as kops
 
         device_id_pack = kops.compiled_supported()
-    los = np.atleast_2d(np.asarray(los, dtype=np.float32))
-    his = np.atleast_2d(np.asarray(his, dtype=np.float32))
-    (los, his), q0 = _pad_batch([los, his], [BIG, -BIG])
-    losj, hisj = jnp.asarray(los), jnp.asarray(his)
-    hits, n_pairs = _frontier_count(dev, losj, hisj, use_kernel)
-    p0 = int(n_pairs)
-    cold = None
-    if return_cold:
-        cold = np.asarray(hits[:q0, dev.n_leaves :])
-    if p0 == 0:
-        empty = [np.zeros(0, dtype=np.int64) for _ in range(q0)]
-        return (empty, cold) if return_cold else empty
-    parts = []
-    per_query = np.zeros(los.shape[0], dtype=np.int64)
-    for a in range(0, p0, PAIR_CHUNK):
-        pc = _pow2(min(p0 - a, PAIR_CHUNK))
-        ids_or, pq, total = _fused_pack_scan(
-            dev, losj, hisj, hits, np.int32(a), pc, use_kernel
-        )
-        per_query += np.asarray(pq, dtype=np.int64)
-        if device_id_pack:
-            t = int(total)
-            if t:
-                packed = np.asarray(_fused_id_pack(ids_or, _pow2(t)))[:t]
-                parts.append(packed.astype(np.int64))
-        else:
-            arr = np.asarray(ids_or)
-            parts.append(arr[arr >= 0].astype(np.int64))
-    all_ids = (
-        np.concatenate(parts) if parts else np.zeros(0, dtype=np.int64)
-    )
-    res = np.split(all_ids, np.cumsum(per_query[:q0])[:-1])
+    with tracing.span("query.window") as sp:
+        los = np.atleast_2d(np.asarray(los, dtype=np.float32))
+        his = np.atleast_2d(np.asarray(his, dtype=np.float32))
+        (los, his), q0 = _pad_batch([los, his], [BIG, -BIG])
+        losj, hisj = jnp.asarray(los), jnp.asarray(his)
+        hits, n_pairs = _frontier_count(dev, losj, hisj, use_kernel)
+        with tracing.span("query.sync", what="pairs"):
+            p0 = int(n_pairs)
+        cold = None
+        if return_cold:
+            with tracing.span("query.sync", what="cold"):
+                cold = np.asarray(hits[:q0, dev.n_leaves :])
+        parts = []
+        per_query = np.zeros(los.shape[0], dtype=np.int64)
+        pair_slots = id_slots = chunks = 0
+        for a in range(0, p0, PAIR_CHUNK):
+            pc = _pow2(min(p0 - a, PAIR_CHUNK))
+            pair_slots += pc
+            chunks += 1
+            ids_or, pq, total = _fused_pack_scan(
+                dev, losj, hisj, hits, np.int32(a), pc, use_kernel
+            )
+            with tracing.span("query.sync", what="per_query"):
+                per_query += np.asarray(pq, dtype=np.int64)
+            if device_id_pack:
+                with tracing.span("query.sync", what="ids_total"):
+                    t = int(total)
+                if t:
+                    r = _pow2(t)
+                    id_slots += r
+                    packed = _fused_id_pack(ids_or, r)
+                    with tracing.span("query.sync", what="ids"):
+                        packed = np.asarray(packed)[:t]
+                    parts.append(packed.astype(np.int64))
+            else:
+                with tracing.span("query.sync", what="id_matrix"):
+                    arr = np.asarray(ids_or)
+                parts.append(arr[arr >= 0].astype(np.int64))
+        with tracing.span("query.split"):
+            all_ids = (
+                np.concatenate(parts) if parts
+                else np.zeros(0, dtype=np.int64)
+            )
+            res = np.split(all_ids, np.cumsum(per_query[:q0])[:-1])
+        sp.set(q=q0, pairs=p0, pair_slots=pair_slots, chunks=chunks,
+               ids=int(per_query.sum()), id_slots=id_slots)
     return (res, cold) if return_cold else res
 
 
@@ -708,7 +714,6 @@ def _pair_collect(
 ):
     """Scan one bucket of (query, leaf) candidate pairs: gather each
     pair's leaf block and test containment against its query's box."""
-    TRACE_COUNTS["window_collect"] += 1
     s = dev.leaf_size
     lo_p = los[q_idx]                         # (P, d)
     hi_p = his[q_idx]
@@ -840,7 +845,6 @@ def _knn_core(
     Returns (ids, d2, exact): ``exact`` certifies the best-first bound —
     the k-th distance does not exceed the mindist of the closest leaf left
     unscanned, so no unscanned leaf can hold a closer neighbor."""
-    TRACE_COUNTS["knn_core"] += 1
     q = qs.shape[0]
     n_l, s, d = dev.leaf_pts.shape
     c = min(n_candidate_leaves, n_l)
@@ -921,7 +925,6 @@ def _knn_core_fused(
     (``pair_dist2``) instead of an XLA-materialized (Q, C*S, d) gather;
     and outputs are padded to the c-independent width ``min(k, L*S)`` so
     escalation rounds scatter into one fixed result buffer."""
-    TRACE_COUNTS["knn_core"] += 1
     q = qs.shape[0]
     n_l, s, d = dev.leaf_pts.shape
     c = min(n_candidate_leaves, n_l)
@@ -995,7 +998,6 @@ def _knn_pending(qs: jnp.ndarray, exact: jnp.ndarray, b0, p: int):
 
     ``b0`` masks the batch's pow2 padding rows (their certificates are
     meaningless and must not consume bucket slots)."""
-    TRACE_COUNTS["knn_sel"] += 1
     fail = (~exact) & (jnp.arange(exact.shape[0]) < b0)
     (idx,) = jnp.nonzero(fail, size=p, fill_value=0)
     idx = idx.astype(jnp.int32)

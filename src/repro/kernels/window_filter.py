@@ -276,6 +276,7 @@ def box_hits_tiled(
         out_specs=pl.BlockSpec((nt, qt), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, nq), jnp.int32),
         interpret=interpret,
+        name="box_hits_tiled",
     )(lo, hi, qlo, qhi)
 
 
@@ -363,6 +364,7 @@ def pair_window_ids(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((q_idx.shape[0], s), jnp.int32),
         interpret=interpret,
+        name="pair_window_ids",
     )(
         q_idx, leaf_idx, live, qlo.reshape(-1), qhi.reshape(-1),
         leaf_pts, leaf_ids,

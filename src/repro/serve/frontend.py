@@ -62,6 +62,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from .. import tracing
 from .resilience import Deadline, DeadlineExceeded, RetryExhausted
 
 
@@ -134,6 +135,9 @@ class FrontendStats:
     brownout_enters: int = 0
     brownout_exits: int = 0
     depth_peak: int = 0
+    dispatched: int = 0        # requests that reached a server call
+    queue_wait_s: float = 0.0  # sum of t_dispatch - t_submit over those
+    hold_s: float = 0.0        # dispatcher waiting on a queued, undue batch
 
     @property
     def dropped(self) -> int:
@@ -152,7 +156,7 @@ class Request:
 
     __slots__ = ("kind", "payload", "t_submit", "deadline", "seq",
                  "status", "reason", "ids", "cert", "brownout",
-                 "t_done", "_event")
+                 "t_dispatch", "t_done", "_event")
 
     def __init__(self, kind, payload, t_submit, deadline, seq):
         self.kind = kind
@@ -165,6 +169,7 @@ class Request:
         self.ids: Optional[np.ndarray] = None
         self.cert = None
         self.brownout = False
+        self.t_dispatch: Optional[float] = None
         self.t_done: Optional[float] = None
         self._event = threading.Event()
 
@@ -415,46 +420,53 @@ class Frontend:
             pass
 
     def _dispatch(self, lane, reqs: list, brown: bool) -> None:
-        now = self.clock()
-        live = []
-        for r in reqs:
-            if r.deadline is not None and now >= r.deadline:
-                self._finish_dropped(
-                    r, "timeout", "deadline expired in queue",
-                    stat="timed_out",
-                )
-            else:
-                live.append(r)
-        if not live:
-            return
-        with self._mu:
-            self.stats.batches += 1
-            if brown:
-                self.stats.brownout_batches += 1
-        budgets = [r.deadline - now for r in live if r.deadline is not None]
-        deadline = Deadline(min(budgets) if budgets else None,
-                            clock=self.clock)
+        with tracing.span("frontend.dispatch") as sp:
+            now = self.clock()
+            live = []
+            for r in reqs:
+                if r.deadline is not None and now >= r.deadline:
+                    self._finish_dropped(
+                        r, "timeout", "deadline expired in queue",
+                        stat="timed_out",
+                    )
+                else:
+                    r.t_dispatch = now
+                    live.append(r)
+            if not live:
+                return
+            waits = [now - r.t_submit for r in live]
+            sp.set(n=len(live), oldest_wait_ms=1e3 * max(waits))
+            with self._mu:
+                self.stats.batches += 1
+                self.stats.dispatched += len(live)
+                self.stats.queue_wait_s += sum(waits)
+                if brown:
+                    self.stats.brownout_batches += 1
+            budgets = [r.deadline - now for r in live
+                       if r.deadline is not None]
+            deadline = Deadline(min(budgets) if budgets else None,
+                                clock=self.clock)
 
-        def attempt():
-            if self.fault_plan is not None:
-                self.fault_plan.fire("batch_close", kind=lane[0])
-            return self._execute(lane, live, deadline, brown)
+            def attempt():
+                if self.fault_plan is not None:
+                    self.fault_plan.fire("batch_close", kind=lane[0])
+                return self._execute(lane, live, deadline, brown)
 
-        try:
-            self.server.retry.call(
-                attempt, no_retry=(DeadlineExceeded,),
-                call_key=("batch_close", lane),
-            )
-        except DeadlineExceeded:
-            for r in live:
-                self._finish_dropped(
-                    r, "timeout", "deadline exceeded during dispatch",
-                    stat="timed_out",
+            try:
+                self.server.retry.call(
+                    attempt, no_retry=(DeadlineExceeded,),
+                    call_key=("batch_close", lane),
                 )
-        except RetryExhausted as e:
-            for r in live:
-                self._finish_dropped(r, "shed", f"dispatch failed: {e}",
-                                     stat="shed")
+            except DeadlineExceeded:
+                for r in live:
+                    self._finish_dropped(
+                        r, "timeout", "deadline exceeded during dispatch",
+                        stat="timed_out",
+                    )
+            except RetryExhausted as e:
+                for r in live:
+                    self._finish_dropped(r, "shed", f"dispatch failed: {e}",
+                                         stat="shed")
 
     def _execute(self, lane, reqs: list, deadline, brown: bool) -> None:
         """One formed microbatch against the engine.  Raises to signal a
@@ -588,9 +600,13 @@ class Frontend:
                     if self._due_lanes(now, False):
                         break
                     nxt = self._next_due(now)
-                    self._mu.wait(
-                        None if nxt is None else max(nxt - now, 0.0)
-                    )
+                    if nxt is None:
+                        self._mu.wait()
+                        continue
+                    # a request is queued, its batch not yet due: the hold
+                    with tracing.span("frontend.hold"):
+                        self._mu.wait(max(nxt - now, 0.0))
+                    self.stats.hold_s += self.clock() - now
                 if self._stopping and self._depth == 0:
                     return
             self.pump(flush=self._stopping)

@@ -12,6 +12,7 @@ time: only one process may load the TPU library, and several test workers
 import this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -210,6 +211,25 @@ def test_fused_pack_scan_compiles(one_chip, compiled_kernels, layout):
     hits = _sds(one_chip, (N_QUERIES, lay["n_leaves"]), jnp.bool_)
     offset = _sds(one_chip, (), jnp.int32)
     _compile(qj._fused_pack_scan, dev, q, q, hits, offset, PAIRS, True)
+
+
+def test_served_kernels_keep_their_trace_names(one_chip, compiled_kernels):
+    """The trace names a Pallas kernel after its custom call: the benchmark
+    finds ``pair_window_ids`` (its roofline) and ``box_hits_tiled`` by
+    these names, so a refactor must keep them."""
+    lay = LAYOUTS["osm_10m_d2"]
+    dev = _table(one_chip, lay)
+    q = _sds(one_chip, (N_QUERIES, lay["d"]))
+    hits = _sds(one_chip, (N_QUERIES, lay["n_leaves"]), jnp.bool_)
+    offset = _sds(one_chip, (), jnp.int32)
+    scan = _compile(qj._fused_pack_scan, dev, q, q, hits, offset, PAIRS, True)
+    frontier = _compile(qj._frontier_count, dev, q, q, True)
+    for compiled, kernel in ((scan, "pair_window_ids"),
+                             (frontier, "box_hits_tiled")):
+        calls = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
+                           r'"tpu_custom_call"', compiled.as_text())
+        assert calls and all(c.rsplit(".", 1)[0] == kernel for c in calls), \
+            calls
 
 
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
