@@ -268,7 +268,7 @@ def run(n: int = 600_000, seed: int = 0, repeats: int = 3) -> dict:
     )
     p0 = int(hit.sum())
     n_boxes = dev.n_leaves + sum(lv[0].shape[0] for lv in dev.levels)
-    s = dev.leaf_pts.shape[1]
+    s = dev.leaf_size
     w_bytes = rf.bytes_box_hits_tiled(
         n_boxes, 64, d
     ) + rf.bytes_pair_window_ids(p0, s, d)

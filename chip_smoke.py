@@ -165,7 +165,7 @@ def boot_server(idx, use_kernel=None):
 
 
 def leaf_table_bytes(dev) -> dict:
-    """Bytes of the ``(L, S, d)`` leaf table: as the array's shape needs
+    """Bytes of the ``(d, L, S)`` leaf table: as the array's shape needs
     them, and as the device stores them (a narrow minor axis may be
     padded out to full lanes)."""
     lp = dev.leaf_pts

@@ -288,7 +288,8 @@ class ShardedDeviceTable:
         return cls.from_tables(tables, points, dtype=dtype)
 
     def stacked(self) -> dict:
-        """Uniform (m, L, S, ·) leaf layout for the ``shard_map`` round.
+        """Uniform leaf layout for the ``shard_map`` round, shard axis
+        first: points (m, d, L, S), ids (m, L, S), fills (m, L).
 
         Shards pad to the widest leaf table with empty leaves (inverted
         MBBs, dtype-max coordinates, zero fill counts) that every masked
@@ -300,17 +301,17 @@ class ShardedDeviceTable:
                 "carry cold rows only the host-routed path can serve)"
             )
         L = max(s.n_leaves for s in self.shards)
-        S = max(s.leaf_size for s in self.shards)
+        S = max(s.slots for s in self.shards)
         d = self.dim
         m = self.m
-        lp = np.full((m, L, S, d), BIG, dtype=np.float32)
+        lp = np.full((m, d, L, S), BIG, dtype=np.float32)
         li = np.full((m, L, S), -1, dtype=np.int32)
         lc = np.zeros((m, L), dtype=np.int32)
         llo = np.full((m, L, d), BIG, dtype=np.float32)
         lhi = np.full((m, L, d), -BIG, dtype=np.float32)
         for s, dev in enumerate(self.shards):
-            ls, ss = dev.n_leaves, dev.leaf_size
-            lp[s, :ls, :ss] = np.asarray(dev.leaf_pts)
+            ls, ss = dev.n_leaves, dev.slots
+            lp[s, :, :ls, :ss] = np.asarray(dev.leaf_pts)[:, :ls]
             li[s, :ls, :ss] = np.asarray(dev.leaf_ids)
             lc[s, :ls] = np.asarray(dev.leaf_counts)
             llo[s, :ls] = np.asarray(dev.leaf_lo)
@@ -594,8 +595,8 @@ def window_count_shard_map_round(mesh, axis: str):
     leaf_counts)`` with the windows replicated."""
 
     def body(los, his, lp_l, lc_l):
-        pts = lp_l[0]                                     # (L, S, d)
-        s, d = pts.shape[1], pts.shape[2]
+        pts = lp_l[0]                                     # (d, L, S)
+        d, s = pts.shape[0], pts.shape[2]
         valid = (
             jnp.arange(s, dtype=jnp.int32)[None, :] < lc_l[0][:, None]
         )                                                  # (L, S)
@@ -604,8 +605,8 @@ def window_count_shard_map_round(mesh, axis: str):
         inside = valid[None]
         for j in range(d):
             inside = inside & (
-                (pts[..., j][None] >= los[:, j][:, None, None])
-                & (pts[..., j][None] <= his[:, j][:, None, None])
+                (pts[j][None] >= los[:, j][:, None, None])
+                & (pts[j][None] <= his[:, j][:, None, None])
             )
         local = jnp.sum(inside, axis=(1, 2)).astype(jnp.int32)
         return jax.lax.psum(local, axis)[None]
@@ -641,7 +642,7 @@ def knn_batch_shard_map(
     lp = _check_mesh(stacked, mesh, axis)
     placed = place_stacked(stacked, mesh, axis)
     qs_j = jnp.asarray(np.atleast_2d(np.asarray(qs, dtype=np.float32)))
-    fn = jax.jit(knn_shard_map_round(mesh, axis, k, lp.shape[1]))
+    fn = jax.jit(knn_shard_map_round(mesh, axis, k, lp.shape[2]))
     d2, ids = fn(qs_j, *(placed[key] for key in STACKED_KEYS))
     # every shard holds the same merged answer; shard 0's copy suffices
     return np.asarray(d2[0]), np.asarray(ids[0])

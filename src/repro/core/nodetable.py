@@ -43,6 +43,20 @@ from .ioutil import atomic_output
 from ..analysis import runtime as _san
 
 
+# The device export stores its leaf tables in whole TPU tiles: a leaf
+# block's slots fill whole 128-wide lane rows, and the point table's leaves
+# whole 8-row sublane tiles.  At those shapes the TPU's default layout is
+# row-major, the layout the pair kernels read; at any other it picks the
+# layout that pads least, which puts the leaf axis on the lanes, and every
+# kernel call would open with a relayout copy of the whole table.
+SLOT_TILE = 128
+LEAF_TILE = 8
+
+
+def round_up(n: int, tile: int) -> int:
+    return -(-int(n) // tile) * tile
+
+
 # --------------------------------------------------------------------------
 # bf16 compressed-MBB export (outward rounding; shared with queries_jax.py)
 # --------------------------------------------------------------------------
@@ -906,27 +920,31 @@ class NodeTable:
 
     # -- device layout --------------------------------------------------------
     def pack_leaf_blocks(
-        self, rows: np.ndarray, points: np.ndarray, S: int, dtype=np.float32
+        self, rows: np.ndarray, points: np.ndarray, S: int, dtype=np.float32,
+        n_blocks: int = 0,
     ) -> tuple[np.ndarray, np.ndarray]:
-        """Uniform ``S``-slot point/id blocks for the given payload rows
-        (padding slots carry ``id = -1`` and dtype-max coordinates).  The
-        device export and the incremental delta refresh share this packing.
+        """Uniform ``S``-slot point/id blocks for the given payload rows:
+        points dimension-major ``(d, max(k, n_blocks), S)``, ids ``(k, S)``
+        (padding slots and blocks carry ``id = -1`` and dtype-max
+        coordinates).  The device export and the incremental delta refresh
+        share this packing.
         """
         d = self.dim
         big = np.finfo(dtype).max
         k = len(rows)
         counts = self.leaf_count[rows]
-        leaf_pts = np.full((k, S, d), big, dtype=dtype)
+        leaf_pts = np.full((d, max(k, n_blocks), S), big, dtype=dtype)
         leaf_ids = np.full((k, S), -1, dtype=np.int32)
         if k:
             sel = ragged_ranges(self.leaf_start[rows], counts)
             within = np.arange(len(sel), dtype=np.int64) - np.repeat(
                 np.cumsum(counts) - counts, counts
             )
-            slot_l = np.repeat(np.arange(k, dtype=np.int64), counts)
+            slot = np.repeat(np.arange(k, dtype=np.int64) * S, counts) + within
             data_rows = self.perm[sel]
-            leaf_pts[slot_l, within] = points[data_rows].astype(dtype)
-            leaf_ids[slot_l, within] = data_rows
+            for j in range(d):  # one column at a time: no (n, d) transpose
+                leaf_pts[j].reshape(-1)[slot] = points[data_rows, j]
+            leaf_ids.reshape(-1)[slot] = data_rows
         return leaf_pts, leaf_ids
 
     def slot_map(
@@ -977,11 +995,15 @@ class NodeTable:
         query-time access is a dense gather (see ``core/queries_jax.py``,
         which wraps these arrays in a jit-able ``DeviceTable`` pytree):
 
-          * ``leaf_pts``/``leaf_ids``  (L, S, d)/(L, S): each leaf's points
-            gathered once through ``perm`` into uniform ``S``-slot blocks
-            (S = max leaf fullness; padding slots carry ``id = -1`` and
-            dtype-max coordinates so containment and distance tests mask
-            them for free);
+          * ``leaf_pts``/``leaf_ids``  (d, L', S')/(L, S'): each leaf's
+            points gathered once through ``perm`` into uniform blocks of S'
+            slots: ``leaf_size`` S, the widest leaf's fill, rounded up to
+            whole lane rows.  Points are dimension-major and their leaf
+            axis runs to L', L rounded up to whole sublane tiles, so the
+            TPU stores both tables as the pair kernels read them
+            (``SLOT_TILE``).  Padding slots and blocks carry ``id = -1``
+            and dtype-max coordinates, so containment and distance tests
+            mask them for free;
           * ``leaf_lo``/``leaf_hi``  (L, d): leaf MBBs, slot-aligned;
           * ``levels``: one block per tree depth — row MBBs, each row's
             parent *position* within the previous level's block, and the
@@ -1021,10 +1043,14 @@ class NodeTable:
         counts = self.leaf_count[rows]
         L = len(rows)
         S = max(int(counts.max()) if L and counts.size else 1, 1)
-        leaf_pts, leaf_ids = self.pack_leaf_blocks(rows, points, S, dtype)
+        leaf_pts, leaf_ids = self.pack_leaf_blocks(
+            rows, points, round_up(S, SLOT_TILE), dtype,
+            n_blocks=round_up(L, LEAF_TILE),
+        )
         slot_of = self.slot_map(rows, cold)
         levels = self.level_blocks(slot_of, dtype)
         layout = {
+            "leaf_size": S,
             "leaf_pts": leaf_pts,
             "leaf_ids": leaf_ids,
             "leaf_counts": counts.astype(np.int32),

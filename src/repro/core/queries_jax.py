@@ -68,7 +68,7 @@ import numpy as np
 
 from .. import tracing
 from .jax_index import _pow2
-from .nodetable import NodeTable
+from .nodetable import LEAF_TILE, SLOT_TILE, NodeTable, round_up
 
 BIG = float(np.finfo(np.float32).max)
 
@@ -199,8 +199,10 @@ class DeviceTable:
     after the host grafts new subtrees.
     """
 
-    leaf_pts: jnp.ndarray    # (L, S, d) leaf-blocked points, pad = dtype max
-    leaf_ids: jnp.ndarray    # (L, S) int32 dataset rows, pad = -1
+    # the two tables hold whole TPU tiles (NodeTable.device_layout): S'
+    # slots a block, a multiple of 128, and L' >= L point blocks, of 8
+    leaf_pts: jnp.ndarray    # (d, L', S') leaf-blocked points, pad = dtype max
+    leaf_ids: jnp.ndarray    # (L, S') int32 dataset rows, pad = -1
     leaf_counts: jnp.ndarray # (L,) int32 live slots per leaf block
     leaf_lo: jnp.ndarray     # (L, d)
     leaf_hi: jnp.ndarray     # (L, d)
@@ -215,6 +217,7 @@ class DeviceTable:
     leaf_hi_c: jnp.ndarray = None  # (L, d) bf16
     levels_c: tuple = None         # per depth: (lo_c, hi_c) bf16
     n_points: int = None
+    fill: int = None         # widest leaf's live slots (see leaf_size)
     leaf_ids_host: np.ndarray = None
     leaf_rows: np.ndarray = None  # (L,) table row behind each leaf slot
     cold_rows: np.ndarray = None  # (U,) table row behind each cold slot
@@ -243,19 +246,30 @@ class DeviceTable:
 
     @property
     def n_leaves(self) -> int:
-        return self.leaf_pts.shape[0]
+        return self.leaf_ids.shape[0]
 
     @property
     def n_cold(self) -> int:
         return 0 if self.cold_lo is None else self.cold_lo.shape[0]
 
     @property
+    def slots(self) -> int:
+        """Slots per stored leaf block: ``leaf_size`` in whole lane rows."""
+        return self.leaf_ids.shape[1]
+
+    @property
     def leaf_size(self) -> int:
-        return self.leaf_pts.shape[1]
+        """The widest leaf's fill (at least 1): the points a leaf block
+        holds at most.  Host scaffolding like ``n_points``, recovered from
+        the fill counts after a pytree round-trip."""
+        if self.fill is None:
+            self.fill = max(int(np.asarray(self.leaf_counts).max(initial=0)),
+                            1)
+        return self.fill
 
     @property
     def dim(self) -> int:
-        return self.leaf_pts.shape[2]
+        return self.leaf_pts.shape[0]
 
     @property
     def host_ids(self) -> np.ndarray:
@@ -305,7 +319,7 @@ class DeviceTable:
         levels = _levels_to_jax(lay["levels"])
         sink = stats if stats is not None else UPLOAD_STATS
         sink.record_export(
-            lay["leaf_pts"].shape[0], int(lay["leaf_counts"].sum())
+            len(lay["leaf_counts"]), int(lay["leaf_counts"].sum())
         )
         return cls(
             leaf_pts=jnp.asarray(lay["leaf_pts"]),
@@ -320,6 +334,7 @@ class DeviceTable:
             leaf_hi_c=(jnp.asarray(lay["leaf_hi_c"]) if compressed else None),
             levels_c=(_levels_c_to_jax(lay["levels"]) if compressed else None),
             n_points=int(lay["leaf_counts"].sum()),
+            fill=lay["leaf_size"],
             leaf_ids_host=lay["leaf_ids"],
             leaf_rows=lay["leaf_rows"],
             cold_rows=lay["cold_rows"],
@@ -358,7 +373,6 @@ class DeviceTable:
             )
         dtype = np.dtype(self.leaf_pts.dtype)
         big = np.finfo(dtype).max
-        d = self.dim
         old_rows = self.leaf_rows
         known = np.zeros(table.n_nodes, dtype=bool)
         known[old_rows] = True
@@ -366,23 +380,29 @@ class DeviceTable:
         new_rows = rows_now[~known[rows_now]]
         leaf_rows = np.concatenate([old_rows, new_rows])
         counts_new = table.leaf_count[new_rows]
-        s_old = self.leaf_size
-        S = max(s_old, int(counts_new.max()) if len(counts_new) else 1)
+        fill = max(self.leaf_size,
+                   int(counts_new.max()) if len(counts_new) else 1)
+        s_old = self.slots
+        S = max(s_old, round_up(fill, SLOT_TILE))
+        l_old = self.n_leaves
         lp, li = self.leaf_pts, self.leaf_ids
         if S > s_old:  # widen existing blocks device-side (no host upload)
-            l_old = self.n_leaves
             lp = jnp.concatenate(
-                [lp, jnp.full((l_old, S - s_old, d), big, dtype=lp.dtype)],
-                axis=1,
+                [lp, jnp.full((*lp.shape[:2], S - s_old), big,
+                              dtype=lp.dtype)],
+                axis=2,
             )
             li = jnp.concatenate(
                 [li, jnp.full((l_old, S - s_old), -1, dtype=li.dtype)], axis=1
             )
         if len(new_rows):
+            # the new blocks replace the point table's padding blocks and
+            # bring their own, out to whole sublane tiles again
             nb_pts, nb_ids = table.pack_leaf_blocks(
-                new_rows, np.asarray(points), S, dtype
+                new_rows, np.asarray(points), S, dtype,
+                n_blocks=round_up(len(leaf_rows), LEAF_TILE) - l_old,
             )
-            lp = jnp.concatenate([lp, jnp.asarray(nb_pts)], axis=0)
+            lp = jnp.concatenate([lp[:, :l_old], jnp.asarray(nb_pts)], axis=1)
             li = jnp.concatenate([li, jnp.asarray(nb_ids)], axis=0)
         cold = np.flatnonzero(table.unrefined)
         level_blocks = table.level_blocks(
@@ -430,6 +450,7 @@ class DeviceTable:
             leaf_hi_c=leaf_hi_c,
             levels_c=levels_c,
             n_points=int(counts.sum()),
+            fill=fill,
             leaf_ids_host=ids_host,
             leaf_rows=leaf_rows,
             cold_rows=cold,
@@ -702,6 +723,13 @@ def _window_batch_fused(
 # --------------------------------------------------------------------------
 # window: pair-list candidate collection
 # --------------------------------------------------------------------------
+def _gather_leaves(leaf_pts: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """Point-major ``idx.shape + (S, d)`` blocks of the leaves ``idx``
+    names: the gather reads the dimension-major table, and the axis moves
+    on the small gathered block, never on the table."""
+    return jnp.moveaxis(leaf_pts[:, idx], 0, -1)
+
+
 @functools.partial(jax.jit, static_argnames=("use_kernel",))
 def _pair_collect(
     dev: DeviceTable,
@@ -714,10 +742,10 @@ def _pair_collect(
 ):
     """Scan one bucket of (query, leaf) candidate pairs: gather each
     pair's leaf block and test containment against its query's box."""
-    s = dev.leaf_size
+    s = dev.slots
     lo_p = los[q_idx]                         # (P, d)
     hi_p = his[q_idx]
-    pts = dev.leaf_pts[leaf_idx]              # (P, S, d)
+    pts = _gather_leaves(dev.leaf_pts, leaf_idx)  # (P, S, d)
     # slot validity from the per-leaf fill counts: no (P, S) id gather
     valid = (
         jnp.arange(s, dtype=jnp.int32)[None, :]
@@ -846,7 +874,7 @@ def _knn_core(
     the k-th distance does not exceed the mindist of the closest leaf left
     unscanned, so no unscanned leaf can hold a closer neighbor."""
     q = qs.shape[0]
-    n_l, s, d = dev.leaf_pts.shape
+    d, n_l, s = dev.dim, dev.n_leaves, dev.slots
     c = min(n_candidate_leaves, n_l)
     # box mindists accumulated per dimension: (Q, L) planes only
     mind = jnp.zeros((q, n_l), dtype=dev.leaf_lo.dtype)
@@ -858,7 +886,7 @@ def _knn_core(
     # indices-only top_k: keeping the values output live trips XLA CPU's
     # slow generic sort path (~10x); the unscanned bound is recovered below
     _, cand = jax.lax.top_k(-mind, c)
-    flat_pts = dev.leaf_pts[cand].reshape(q, c * s, d)
+    flat_pts = _gather_leaves(dev.leaf_pts, cand).reshape(q, c * s, d)
     if use_kernel:
         from ..kernels import ops as kops
 
@@ -926,7 +954,7 @@ def _knn_core_fused(
     and outputs are padded to the c-independent width ``min(k, L*S)`` so
     escalation rounds scatter into one fixed result buffer."""
     q = qs.shape[0]
-    n_l, s, d = dev.leaf_pts.shape
+    d, n_l, s = dev.dim, dev.n_leaves, dev.slots
     c = min(n_candidate_leaves, n_l)
     if dev.leaf_lo_c is not None:
         blo, bhi = dev.leaf_lo_c, dev.leaf_hi_c
@@ -951,7 +979,7 @@ def _knn_core_fused(
             qs, dev.leaf_pts, dev.leaf_counts, q_rep, cand.reshape(-1)
         ).reshape(q, c, s)
     else:
-        flat_pts = dev.leaf_pts[cand].reshape(q, c * s, d)
+        flat_pts = _gather_leaves(dev.leaf_pts, cand).reshape(q, c * s, d)
         d2 = jnp.sum((flat_pts - qs[:, None, :]) ** 2, axis=2).reshape(
             q, c, s
         )

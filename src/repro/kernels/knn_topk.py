@@ -183,11 +183,12 @@ def _pair_dist2_kernel(q_idx_ref, leaf_idx_ref, live_ref, q_ref,  # prefetch
                        pts_ref, out_ref):
     i = pl.program_id(0)
     q = q_idx_ref[i]
-    s, d = pts_ref.shape[1], pts_ref.shape[2]
-    pt = pts_ref[0].T                       # (d, S): this pair's leaf block
+    d, s = pts_ref.shape[0], pts_ref.shape[2]
+    # the leaf's row out of its PAIR_ROWS-row tile of the point table
+    row = pl.ds(leaf_idx_ref[i] % PAIR_ROWS, 1)
     acc = jnp.zeros((1, s), jnp.float32)
     for k in range(d):                      # static unroll over dimensions
-        diff = pt[k:k + 1, :] - q_ref[q * d + k]
+        diff = pts_ref[k, row, :] - q_ref[q * d + k]
         acc = acc + diff * diff
     valid = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1) < live_ref[i]
     big = jnp.asarray(jnp.finfo(jnp.float32).max, jnp.float32)
@@ -197,7 +198,7 @@ def _pair_dist2_kernel(q_idx_ref, leaf_idx_ref, live_ref, q_ref,  # prefetch
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pair_dist2(
     queries: jnp.ndarray,     # (nq, d) float32 query points
-    leaf_pts: jnp.ndarray,    # (L, S, d) float32 leaf-blocked points
+    leaf_pts: jnp.ndarray,    # (d, L' >= L, S) float32 leaf-blocked points
     leaf_counts: jnp.ndarray, # (L,) int32 live slots per block
     q_idx: jnp.ndarray,       # (P,) int32 query of each candidate pair
     leaf_idx: jnp.ndarray,    # (P,) int32 leaf slot of each candidate pair
@@ -206,15 +207,16 @@ def pair_dist2(
 ) -> jnp.ndarray:
     """Fused (query, leaf) candidate scan: (P, S) squared distances.
 
-    Each pair's leaf block streams from the (L, S, d) table straight into
+    Each pair's leaf block streams from the (d, L, S) table straight into
     VMEM through scalar-prefetch BlockSpec index maps — no XLA-materialized
-    (P, S, d) gather.  Invalid slots carry float32 max so they sort last in
-    the top-k merge.  The block layout follows
-    ``window_filter.pair_window_ids``: query points and per-pair live-slot
-    counts ride in SMEM, and each step writes one row of a
+    (P, S, d) gather and no relayout of the table.  Invalid slots carry
+    float32 max so they sort last in the top-k merge.  The block layout
+    follows ``window_filter.pair_window_ids``: query points and per-pair
+    live-slot counts ride in SMEM, the leaf's rows are read out of their
+    ``PAIR_ROWS``-row tile, and each step writes one row of a
     ``PAIR_ROWS``-row output tile."""
     n_p = q_idx.shape[0]
-    _, s, d = leaf_pts.shape
+    d, _, s = leaf_pts.shape
     q_idx, leaf_idx, live = _pad_pairs(
         q_idx, leaf_idx, leaf_counts[leaf_idx]
     )
@@ -222,7 +224,8 @@ def pair_dist2(
         num_scalar_prefetch=4,
         grid=(q_idx.shape[0],),
         in_specs=[
-            pl.BlockSpec((1, s, d), lambda i, q, l, n, a: (l[i], 0, 0)),
+            pl.BlockSpec((d, PAIR_ROWS, s),
+                         lambda i, q, l, n, a: (0, l[i] // PAIR_ROWS, 0)),
         ],
         out_specs=pl.BlockSpec((PAIR_ROWS, s),
                                lambda i, q, l, n, a: (i // PAIR_ROWS, 0)),

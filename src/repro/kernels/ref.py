@@ -82,12 +82,13 @@ def box_hits_tiled_ref(lo, hi, qlo, qhi):
 
 def pair_window_ids_ref(qlo, qhi, leaf_lo, leaf_hi, leaf_pts, leaf_ids,
                         leaf_counts, q_idx, leaf_idx, pair_valid):
-    """Reference fused pair scan: plain gathers, ids-or-minus-one."""
+    """Reference fused pair scan: plain gathers out of the (d, L, S)
+    leaf table, ids-or-minus-one."""
     lo_p = qlo[q_idx]                         # (P, d)
     hi_p = qhi[q_idx]
-    pts = leaf_pts[leaf_idx]                  # (P, S, d)
+    pts = jnp.moveaxis(leaf_pts[:, leaf_idx], 0, -1)  # (P, S, d)
     ids = leaf_ids[leaf_idx]                  # (P, S)
-    s = leaf_pts.shape[1]
+    s = leaf_pts.shape[2]
     valid = (
         jnp.arange(s, dtype=jnp.int32)[None, :]
         < leaf_counts[leaf_idx][:, None]
@@ -122,10 +123,11 @@ def leaf_mindist_ref(queries, leaf_lo, leaf_hi):
 
 
 def pair_dist2_ref(queries, leaf_pts, leaf_counts, q_idx, leaf_idx):
-    """Reference fused pair distances: plain gathers, invalid = f32 max."""
+    """Reference fused pair distances: plain gathers out of the (d, L, S)
+    leaf table, invalid = f32 max."""
     q = queries[q_idx]                        # (P, d)
-    pts = leaf_pts[leaf_idx]                  # (P, S, d)
-    s = leaf_pts.shape[1]
+    pts = jnp.moveaxis(leaf_pts[:, leaf_idx], 0, -1)  # (P, S, d)
+    s = leaf_pts.shape[2]
     d2 = jnp.sum((pts - q[:, None, :]) ** 2, axis=2)
     valid = (
         jnp.arange(s, dtype=jnp.int32)[None, :]

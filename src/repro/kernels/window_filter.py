@@ -294,13 +294,13 @@ def _pair_window_ids_kernel(
 ):
     i = pl.program_id(0)
     q = q_idx_ref[i]
-    s, d = pts_ref.shape[1], pts_ref.shape[2]
-    pt = pts_ref[0].T                       # (d, S): this pair's leaf block
-    # the leaf's id row out of its PAIR_ROWS-row tile of the id table
-    ids = ids_ref[pl.ds(leaf_idx_ref[i] % PAIR_ROWS, 1), :]      # (1, S)
+    d, s = pts_ref.shape[0], pts_ref.shape[2]
+    # the leaf's row out of its PAIR_ROWS-row tile of the point and id tables
+    row = pl.ds(leaf_idx_ref[i] % PAIR_ROWS, 1)
+    ids = ids_ref[row, :]                   # (1, S)
     acc = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1) < live_ref[i]
     for k in range(d):                      # exact containment on f32 points
-        pk = pt[k:k + 1, :]                 # (1, S)
+        pk = pts_ref[k, row, :]             # (1, S)
         acc = acc & (pk >= qlo_ref[q * d + k]) & (pk <= qhi_ref[q * d + k])
     out_ref[pl.ds(i % PAIR_ROWS, 1), :] = jnp.where(acc, ids, -1)
 
@@ -311,7 +311,7 @@ def pair_window_ids(
     qhi: jnp.ndarray,       # (nq, d)
     leaf_lo: jnp.ndarray,   # (L, d) exact f32 leaf MBB lows
     leaf_hi: jnp.ndarray,   # (L, d)
-    leaf_pts: jnp.ndarray,  # (L, S, d) float32 leaf-blocked points
+    leaf_pts: jnp.ndarray,  # (d, L' >= L, S) float32 leaf-blocked points
     leaf_ids: jnp.ndarray,  # (L, S) int32 dataset rows, pad = -1
     leaf_counts: jnp.ndarray,  # (L,) int32 live slots per block
     q_idx: jnp.ndarray,     # (P,) int32 query of each candidate pair
@@ -325,22 +325,27 @@ def pair_window_ids(
     ``ids_or[p, s]`` is the dataset row of slot ``s`` of pair ``p``'s leaf
     when the point lies inside the pair's query window, else ``-1``; the
     device packing stage compacts the non-negatives.  The pair's leaf block
-    is pulled straight from the (L, S, d) leaf table into VMEM through a
+    is pulled straight from the (d, L, S) leaf table into VMEM through a
     scalar-prefetch BlockSpec index map — the gather that the
     first-generation path materialized as an XLA (P, S, d) temporary is
-    fused into the kernel's block streaming.
+    fused into the kernel's block streaming.  The table is dimension-major,
+    a leaf's slots on the lanes, and the export pads it to whole (8, 128)
+    tiles (``NodeTable.device_layout``), the shapes the TPU stores
+    row-major, as the kernel reads them; at any other shape every call
+    would open with a relayout copy of the whole table.
 
     Mosaic only blocks the last two axes of an array in whole (8, 128)
     tiles, so nothing here is a one-row block of a larger array: the
-    query boxes ride in SMEM with the other per-pair scalars, the id row
-    is read out of its ``PAIR_ROWS``-row tile, and each grid step writes
-    one row of a ``PAIR_ROWS``-row output tile that stays resident until
-    the pair index leaves it.  The certified f32 re-check of each pair's
-    exact leaf box (a pair surfaced by the widened bf16 frontier whose
-    exact MBB misses the window is dropped) and the padding mask fold into
-    the pair's live-slot count before the kernel runs."""
+    query boxes ride in SMEM with the other per-pair scalars, the leaf's
+    point and id rows are read out of their ``PAIR_ROWS``-row tiles (a
+    tile stays resident while consecutive pairs read it), and each grid
+    step writes one row of a ``PAIR_ROWS``-row output tile that stays
+    resident until the pair index leaves it.  The certified f32 re-check
+    of each pair's exact leaf box (a pair surfaced by the widened bf16
+    frontier whose exact MBB misses the window is dropped) and the padding
+    mask fold into the pair's live-slot count before the kernel runs."""
     n_p = q_idx.shape[0]
-    _, s, d = leaf_pts.shape
+    d, _, s = leaf_pts.shape
     box_ok = jnp.all(
         (leaf_lo[leaf_idx].astype(jnp.float32) <= qhi[q_idx])
         & (leaf_hi[leaf_idx].astype(jnp.float32) >= qlo[q_idx]),
@@ -352,7 +357,8 @@ def pair_window_ids(
         num_scalar_prefetch=5,
         grid=(q_idx.shape[0],),
         in_specs=[
-            pl.BlockSpec((1, s, d), lambda i, q, l, n, a, b: (l[i], 0, 0)),
+            pl.BlockSpec((d, PAIR_ROWS, s),
+                         lambda i, q, l, n, a, b: (0, l[i] // PAIR_ROWS, 0)),
             pl.BlockSpec((PAIR_ROWS, s),
                          lambda i, q, l, n, a, b: (l[i] // PAIR_ROWS, 0)),
         ],

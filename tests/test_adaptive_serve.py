@@ -20,6 +20,7 @@ import pytest
 from repro.core import AMBI, PageStore, bulk_load, knn_oracle, window_oracle
 from repro.core import queries_jax as QJ
 from repro.core.geometry import boxes_intersect_windows
+from repro.core.nodetable import LEAF_TILE, SLOT_TILE, round_up
 from repro.core.queries import knn_query_batch, window_query_batch
 from repro.core.queries_jax import (
     DeviceTable,
@@ -217,6 +218,37 @@ def test_apply_delta_matches_full_export_and_uploads_only_new_leaves():
         fk = knn_query_batch_jax(fresh, qs, 8)
         for a, b in zip(rk, fk):
             assert np.array_equal(a, b)
+
+
+def test_exports_pad_the_tables_to_whole_tiles():
+    """A full export and each delta refresh hold each leaf's points
+    dimension-major in whole TPU tiles: slots in whole lane rows, the
+    point table's leaves in whole sublane tiles, padding masked out."""
+    pts = _f32_points(60_000, 2, 7)
+    ambi = AMBI(pts, 120)
+    tables = [DeviceTable.from_table(ambi.table, pts, partial=True)]
+    rng = np.random.default_rng(3)
+    for _ in range(3):
+        c = rng.random(2) * 0.6 + 0.2
+        ambi.window(c - 0.04, c + 0.04)  # grafts
+        tables.append(tables[-1].apply_delta(ambi.table, pts))
+    assert tables[-1].n_leaves > tables[0].n_leaves
+    big = np.finfo(np.float32).max
+    for d in tables:
+        fills = np.asarray(d.leaf_counts)
+        assert d.leaf_size == max(int(fills.max(initial=0)), 1)
+        assert d.slots == round_up(d.leaf_size, SLOT_TILE)
+        assert d.leaf_pts.shape == (2, round_up(d.n_leaves, LEAF_TILE),
+                                    d.slots)
+        lp, li = np.asarray(d.leaf_pts), np.asarray(d.leaf_ids)
+        live = li >= 0
+        assert np.array_equal(live.sum(axis=1), fills)
+        np.testing.assert_array_equal(
+            np.moveaxis(lp[:, : d.n_leaves], 0, -1)[live],
+            pts[li[live]].astype(np.float32),
+        )
+        assert np.all(lp[:, : d.n_leaves][:, ~live] == big)
+        assert np.all(lp[:, d.n_leaves:] == big)
 
 
 def test_apply_delta_requires_scaffolding_after_pytree_roundtrip():

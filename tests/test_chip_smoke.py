@@ -19,6 +19,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
 import chip_smoke as cs  # noqa: E402
+from repro.core.nodetable import LEAF_TILE, SLOT_TILE, round_up  # noqa: E402
 
 N = 100_000
 
@@ -73,8 +74,9 @@ def test_script_alone_exits_nonzero(tmp_path):
 # -- (b) build and boot ----------------------------------------------------
 def test_leaf_table_bytes(server):
     lt = cs.leaf_table_bytes(server.dev)
-    n_l, s, d = lt["shape"]
-    assert d == 2 and n_l == server.dev.n_leaves
+    d, n_l, s = lt["shape"]
+    assert d == 2 and n_l == round_up(server.dev.n_leaves, LEAF_TILE)
+    assert s == server.dev.slots and s % SLOT_TILE == 0
     assert lt["logical_bytes"] == n_l * s * d * 4
     assert lt["device_bytes"] >= lt["logical_bytes"]
     assert lt["ratio"] == lt["device_bytes"] / lt["logical_bytes"]

@@ -5,6 +5,7 @@ import pytest
 
 from repro.core import jax_index
 from repro.kernels import ops
+from repro.kernels.window_filter import PAIR_ROWS
 
 
 def _index(n, d, levels, seed):
@@ -187,8 +188,11 @@ def test_box_hits_tiled_matches_ref(d, box_dtype):
     assert np.asarray(got).sum() > 0
 
 
-def _pair_workload(seed, p=37, n_l=12, s=64, d=3):
-    """A (query, leaf) pair workload with ragged leaves and padding pairs."""
+def _pair_workload(seed, p=37, n_l=12, s=64, d=3, last_tile=False):
+    """A (query, leaf) pair workload with ragged leaves and padding pairs;
+    the leaf table is dimension-major (d, L, S), as the device stores it.
+    ``last_tile`` draws every pair's leaf from the last, partial
+    ``PAIR_ROWS``-row tile of the table."""
     rng = np.random.default_rng(seed)
     leaf_pts = rng.random((n_l, s, d)).astype(np.float32)
     leaf_counts = rng.integers(1, s + 1, n_l).astype(np.int32)
@@ -206,8 +210,10 @@ def _pair_workload(seed, p=37, n_l=12, s=64, d=3):
     qlo = rng.random((nq, d)).astype(np.float32) * 0.6
     qhi = qlo + 0.35
     q_idx = rng.integers(0, nq, p).astype(np.int32)
-    leaf_idx = rng.integers(0, n_l, p).astype(np.int32)
+    first = (n_l - 1) // PAIR_ROWS * PAIR_ROWS if last_tile else 0
+    leaf_idx = rng.integers(first, n_l, p).astype(np.int32)
     pair_valid = (rng.random(p) > 0.2).astype(np.int32)
+    leaf_pts = np.ascontiguousarray(leaf_pts.transpose(2, 0, 1))
     return (qlo, qhi, leaf_lo, leaf_hi, leaf_pts, leaf_ids, leaf_counts,
             q_idx, leaf_idx, pair_valid)
 
@@ -262,9 +268,41 @@ def test_pair_dist2_matches_ref(seed):
         np.asarray(got), np.asarray(want), rtol=1e-6, atol=0
     )
     # dead slots carry the f32-max sentinel, never a finite distance
-    s = leaf_pts.shape[1]
+    s = leaf_pts.shape[2]
     dead = np.arange(s)[None, :] >= leaf_counts[leaf_idx][:, None]
     assert np.all(np.asarray(got)[dead] == np.finfo(np.float32).max)
+
+
+# leaf tables whose pairs read a PAIR_ROWS-row tile that runs past the table
+PARTIAL_TILES = {
+    "ragged_l": dict(n_l=13),                 # L not a multiple of PAIR_ROWS
+    "last_tile": dict(n_l=13, last_tile=True),  # every pair in the last tile
+    "below_one_tile": dict(n_l=5, last_tile=True),
+    "d5": dict(d=5, n_l=21),                  # the 5-D trip layout's width
+    "d5_last_tile": dict(d=5, n_l=21, last_tile=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARTIAL_TILES))
+def test_pair_window_ids_matches_ref_on_partial_tiles(case):
+    w = [jnp.asarray(x) for x in _pair_workload(3, **PARTIAL_TILES[case])]
+    gi, gc = ops.pair_window_ids(*w)
+    ri, rc = ops.pair_window_ids_ref(*w)
+    np.testing.assert_array_equal(np.asarray(gi), np.asarray(ri))
+    np.testing.assert_array_equal(np.asarray(gc), np.asarray(rc))
+    assert np.asarray(gc).sum() > 0
+
+
+@pytest.mark.parametrize("case", sorted(PARTIAL_TILES))
+def test_pair_dist2_matches_ref_on_partial_tiles(case):
+    (q, _, _, _, leaf_pts, _, leaf_counts, q_idx, leaf_idx,
+     _) = [jnp.asarray(x) for x in _pair_workload(4, **PARTIAL_TILES[case])]
+    got = ops.pair_dist2(q, leaf_pts, leaf_counts, q_idx, leaf_idx)
+    want = ops.pair_dist2_ref(q, leaf_pts, leaf_counts, q_idx, leaf_idx)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=1e-6, atol=0
+    )
+    assert (np.asarray(got) < np.finfo(np.float32).max).any()
 
 
 def test_box_hits_tiled_compiled_matches_interpret():

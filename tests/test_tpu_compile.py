@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.core import distributed_jax as dj
 from repro.core import queries_jax as qj
+from repro.core.nodetable import LEAF_TILE, SLOT_TILE, round_up
 from repro.core.pagestore import leaf_capacity
 from repro.kernels import knn_topk, ops, window_filter
 
@@ -64,6 +65,15 @@ def _sds(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _tables(sharding, d, n_l):
+    """The leaf point and id tables as ``NodeTable.device_layout`` shapes
+    them for ``n_l`` full leaves: whole lane rows of slots, the point
+    table's leaves in whole sublane tiles."""
+    s = round_up(leaf_capacity(d), SLOT_TILE)
+    return (_sds(sharding, (d, round_up(n_l, LEAF_TILE), s)),
+            _sds(sharding, (n_l, s), jnp.int32))
+
+
 def _compile(fn, *args, kernel=True):
     """Compile ``fn`` (jitted already, or a plain wrapper) for the chip;
     the program must hold a Mosaic kernel and fit the chip's HBM."""
@@ -78,7 +88,7 @@ def _compile(fn, *args, kernel=True):
 def _table(sharding, layout, compressed=False):
     """A ``DeviceTable`` of shapes only, at one of the 10M layouts."""
     d, n_l = layout["d"], layout["n_leaves"]
-    s = leaf_capacity(d)
+    pts, ids = _tables(sharding, d, n_l)
 
     def f(*shape, dtype=jnp.float32):
         return _sds(sharding, shape, dtype)
@@ -89,8 +99,8 @@ def _table(sharding, layout, compressed=False):
     )
     bf = jnp.bfloat16
     return qj.DeviceTable(
-        leaf_pts=f(n_l, s, d),
-        leaf_ids=f(n_l, s, dtype=jnp.int32),
+        leaf_pts=pts,
+        leaf_ids=ids,
         leaf_counts=f(n_l, dtype=jnp.int32),
         leaf_lo=f(n_l, d),
         leaf_hi=f(n_l, d),
@@ -135,14 +145,12 @@ def test_leaf_mindist_tiled_compiles(one_chip, compiled_kernels, layout,
 def test_pair_window_ids_compiles(one_chip, compiled_kernels, layout):
     lay = LAYOUTS[layout]
     d, n_l = lay["d"], lay["n_leaves"]
-    s = leaf_capacity(d)
     i32 = jnp.int32
     _compile(
         ops.pair_window_ids,
         _sds(one_chip, (N_QUERIES, d)), _sds(one_chip, (N_QUERIES, d)),
         _sds(one_chip, (n_l, d)), _sds(one_chip, (n_l, d)),
-        _sds(one_chip, (n_l, s, d)), _sds(one_chip, (n_l, s), i32),
-        _sds(one_chip, (n_l,), i32),
+        *_tables(one_chip, d, n_l), _sds(one_chip, (n_l,), i32),
         _sds(one_chip, (PAIRS,), i32), _sds(one_chip, (PAIRS,), i32),
         _sds(one_chip, (PAIRS,), i32),
     )
@@ -153,11 +161,10 @@ def test_pair_window_ids_compiles(one_chip, compiled_kernels, layout):
 def test_pair_dist2_compiles(one_chip, compiled_kernels, layout, pairs):
     lay = LAYOUTS[layout]
     d, n_l = lay["d"], lay["n_leaves"]
-    s = leaf_capacity(d)
     i32 = jnp.int32
     _compile(
         ops.pair_dist2,
-        _sds(one_chip, (N_QUERIES, d)), _sds(one_chip, (n_l, s, d)),
+        _sds(one_chip, (N_QUERIES, d)), _tables(one_chip, d, n_l)[0],
         _sds(one_chip, (n_l,), i32),
         _sds(one_chip, (pairs,), i32), _sds(one_chip, (pairs,), i32),
     )
@@ -168,14 +175,13 @@ def test_pair_kernels_compile_on_a_tiny_table(one_chip, compiled_kernels,
                                               n_l):
     """An adaptive table early in its refinement holds fewer leaves than
     one ``PAIR_ROWS`` id tile."""
-    s, d, i32 = leaf_capacity(2), 2, jnp.int32
+    d, i32 = 2, jnp.int32
     pairs = (_sds(one_chip, (8,), i32), _sds(one_chip, (8,), i32))
-    pts = _sds(one_chip, (n_l, s, d))
+    pts, ids = _tables(one_chip, d, n_l)
     counts = _sds(one_chip, (n_l,), i32)
     q = _sds(one_chip, (N_QUERIES, d))
     box = _sds(one_chip, (n_l, d))
-    _compile(ops.pair_window_ids, q, q, box, box, pts,
-             _sds(one_chip, (n_l, s), i32), counts, *pairs,
+    _compile(ops.pair_window_ids, q, q, box, box, pts, ids, counts, *pairs,
              _sds(one_chip, (8,), i32))
     _compile(ops.pair_dist2, q, pts, counts, *pairs)
 
@@ -243,13 +249,44 @@ def test_knn_core_fused_compiles(one_chip, compiled_kernels, layout,
     _compile(qj._knn_core_fused, dev, qs, b0, 16, n_candidate_leaves, True)
 
 
+# a point-major (L, S, d) table made each stage copy the whole table into a
+# lane-padded temporary per call: 5.23 GB (scan) and 5.19 GB (k-NN) at the
+# osm layout
+TABLE_COPY_FREE_BYTES = 0.5e9
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("stage", ["scan", "knn"])
+def test_stages_do_not_copy_the_leaf_table(one_chip, compiled_kernels,
+                                           layout, stage):
+    """At the export's tile-padded shapes the TPU stores the dimension-major
+    point table and the id table row-major, as the pair kernels read them,
+    so neither stage relayouts a table per call."""
+    lay = LAYOUTS[layout]
+    dev = _table(one_chip, lay)
+    q = _sds(one_chip, (N_QUERIES, lay["d"]))
+    scalar = _sds(one_chip, (), jnp.int32)
+    if stage == "scan":
+        hits = _sds(one_chip, (N_QUERIES, lay["n_leaves"]), jnp.bool_)
+        compiled = _compile(qj._fused_pack_scan, dev, q, q, hits, scalar,
+                            PAIRS, True)
+    else:
+        compiled = _compile(qj._knn_core_fused, dev, q, scalar, 16, 64, True)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TABLE_COPY_FREE_BYTES, temp
+    for table in (dev.leaf_pts, dev.leaf_ids):
+        shape = ",".join(map(str, table.shape))
+        copy = re.search(rf"\[{shape}\]\{{[^}}]*\}} copy\(",
+                         compiled.as_text())
+        assert copy is None, copy.group(0)
+
 
 # -- the four-chip collective rounds (chip_smoke.py --four-chips) ---------
 def test_shard_map_rounds_compile_on_four_chips(topo):
     """The k-NN and window-count ``shard_map`` rounds over a 4-shard
     stacked table of the 10M osm layout, one shard per chip."""
     lay = LAYOUTS["osm_10m_d2"]
-    d, s = lay["d"], leaf_capacity(lay["d"])
+    d, s = lay["d"], round_up(leaf_capacity(lay["d"]), SLOT_TILE)
     n_l = -(-lay["n_leaves"] // 4)    # leaves per shard, balanced split
     mesh = jax.sharding.Mesh(topo.devices[:4], ("data",),
                              axis_types=(jax.sharding.AxisType.Auto,))
@@ -257,7 +294,7 @@ def test_shard_map_rounds_compile_on_four_chips(topo):
     rep = NamedSharding(mesh, PartitionSpec())
     i32 = jnp.int32
     q = _sds(rep, (32, d))
-    pts = _sds(split, (4, n_l, s, d))
+    pts = _sds(split, (4, d, n_l, s))
     counts = _sds(split, (4, n_l), i32)
     knn = _compile(
         dj.knn_shard_map_round(mesh, "data", 16, n_l),

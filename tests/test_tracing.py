@@ -125,7 +125,7 @@ def _recount(dev, los, his):
     n = dev.n_leaves
     leaf_lo = np.asarray(dev.leaf_lo)[:n]
     leaf_hi = np.asarray(dev.leaf_hi)[:n]
-    pts = np.asarray(dev.leaf_pts)[:n]
+    pts = np.moveaxis(np.asarray(dev.leaf_pts), 0, -1)[:n]
     counts = np.asarray(dev.leaf_counts)[:n]
     lo = los.astype(np.float32)
     hi = his.astype(np.float32)
