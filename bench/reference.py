@@ -33,8 +33,22 @@ Numbers compared (each printed beside its limit):
   the queue, so each is a lost answer.  Limit 0.
 
 Each number's limit is set from chip readings of the program and of the
-control (PERF.md).  ``knn_gap`` has none yet: no cell sends k-NN requests,
-so a cell that does is refused until its limit is measured and set here.
+control (PERF.md); a kind of request whose number has no limit here is
+refused before a run.
+
+``knn_gap``'s limit follows from float32 rounding.  Coordinates and
+queries are float32-exact, and the device sums d float32 squares of
+float32 differences, so each squared distance it ranks by is within a
+relative ``g = (d + 2) u / (1 - (d + 2) u)`` of the exact one (u = 2**-24:
+one rounding for the difference, doubled by the square, one for the
+product, at most d - 1 for the sums; every term is non-negative).  The
+box mindists that certify the candidate leaves carry the same ``g``.  A
+point the answer lacks therefore lies no nearer than ``(1 - g) / (1 + g)``
+times any point it holds, and the i-th distance of the answer is at most
+``(1 + g) / (1 - g)`` times the reference's: ``knn_gap <= 2g / (1 - g)``,
+about ``2 (d + 2) u``, 8.3e-7 at d = 5, the widest configuration.  The
+limit, 1e-5, is twelve times that at d = 5; the bfloat16 control reads
+0.1 and more (PERF.md gives the chip readings of both).
 """
 from __future__ import annotations
 
@@ -42,7 +56,7 @@ import numpy as np
 
 MALFORMED = 1.0e6
 # each number's limit; PERF.md gives the readings each was set from
-LIMITS = {"not_ok": 0, "window_wrong_ids": 0}
+LIMITS = {"not_ok": 0, "window_wrong_ids": 0, "knn_gap": 1e-5}
 # the number that compares each kind of request
 NUMBER_OF_KIND = {"window": "window_wrong_ids", "knn": "knn_gap"}
 
@@ -103,11 +117,20 @@ class BruteForce:
         return np.sort(self.order[s][inside])
 
     def knn_bf16(self, q, k: int) -> np.ndarray:
-        """Ids of the k least bfloat16 distances (ties by id)."""
+        """Ids of the k least bfloat16 distances (ties by id).
+
+        Only points in a box around ``q`` are ranked.  Rounding to
+        bfloat16 moves a coordinate in [0, 1] by at most 2**-9 and each
+        operation by a relative 2**-9, so for d <= 5 no point farther
+        than ``1.02 r + 0.02`` from ``q`` (r the exact k-th distance) can
+        come before the exact k nearest; the box is that wide in each
+        dimension."""
         r = float(np.sqrt(self.knn_dist2(q, k)[-1]))
-        s = self._slab(q[0] - r - 0.05, q[0] + r + 0.05)
-        cand = self.order[s]
-        p, qb = to_bf16(self.sorted[s]), to_bf16(q)
+        w = 1.02 * r + 0.02
+        s = self._slab(q[0] - w, q[0] + w)
+        near = np.all(np.abs(self.sorted[s] - q) <= w, axis=1)
+        cand = self.order[s][near]
+        p, qb = to_bf16(self.sorted[s][near]), to_bf16(q)
         acc = np.zeros(len(cand))
         for j in range(p.shape[1]):
             diff = to_bf16(p[:, j] - qb[j])

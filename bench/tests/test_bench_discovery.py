@@ -6,10 +6,13 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from bench import harness as h  # noqa: E402
+from bench.tests import mixes  # noqa: E402
 
 
 def _checkout(tmp_path):
@@ -21,37 +24,52 @@ def _checkout(tmp_path):
             for p in tmp_path.rglob("*") if p.is_file()}
 
 
-def test_new_files_are_found_by_name(tmp_path):
-    before = _checkout(tmp_path)
-    bench = tmp_path / "bench"
-    # the new files: a configuration, a mix and a metric reader
-    (bench / "configs" / "unif-1k.json").write_text(json.dumps(
+# the new files of each case: a configuration, a mix and the cell's name
+NEW_CELLS = {
+    "unif-1k.tiny": (
         {"generator": "osm_like", "n_points": 1000, "dim": 2,
-         "data_seed": 3, "buffer_fraction": 0.05}))
-    (bench / "traffic" / "tiny-windows.json").write_text(json.dumps(
+         "data_seed": 3, "buffer_fraction": 0.05},
         {"loop": "open", "rate": 10.0, "queue_bound": 100,
          "mix": [{"kind": "window", "share": 1.0, "half_width": [0.1, 0.1],
                   "centre": {"from": "data"}}],
-         "check": 5, "warmup_seconds": 0.1, "warmup_passes": 1}))
+         "check": 5, "warmup_seconds": 0.1, "warmup_passes": 1}),
+    # the next deployment: 5-D trips, windows and k-NN mixed
+    "nycyt5-1k.mixed": (
+        {"generator": "nycyt_like", "n_points": 1000, "dim": 5,
+         "data_seed": 0, "buffer_fraction": 0.05},
+        mixes.MIXED),
+}
+
+
+@pytest.mark.parametrize("cell_name", sorted(NEW_CELLS))
+def test_new_files_are_found_by_name(tmp_path, cell_name):
+    before = _checkout(tmp_path)
+    bench = tmp_path / "bench"
+    config_name, traffic_name = cell_name.split(".")
+    config, traffic = NEW_CELLS[cell_name]
+    # the new files: a configuration, a mix and a metric reader
+    (bench / "configs" / f"{config_name}.json").write_text(json.dumps(config))
+    (bench / "traffic" / f"{traffic_name}.json").write_text(
+        json.dumps(traffic))
     (bench / "metrics" / "tiny.answered.py").write_text(
         "def read(ctx):\n    return float(sum(ctx.records.ok))\n")
     # ... and entries in the manifest
     manifest = json.loads((tmp_path / "BENCHMARK.json").read_text())
-    manifest["configs"].append({"name": "unif-1k", "source": "x",
-                                "file": "bench/configs/unif-1k.json",
+    manifest["configs"].append({"name": config_name, "source": "x",
+                                "file": f"bench/configs/{config_name}.json",
                                 "reduced": [], "why": "test"})
-    manifest["workloads"].append({"name": "unif-1k.tiny", "config": "unif-1k",
-                                  "traffic": "tiny-windows", "chips": 1,
+    manifest["workloads"].append({"name": cell_name, "config": config_name,
+                                  "traffic": traffic_name, "chips": 1,
                                   "why": "test"})
     manifest["per_layer"].append({"name": "tiny.answered", "unit": "count",
                                   "better": "higher", "source": "host_clock",
                                   "layer": "test", "moves": "qps",
-                                  "workloads": ["unif-1k.tiny"]})
+                                  "workloads": [cell_name]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
 
-    cell = h.resolve_cell(h.load_manifest(tmp_path), "unif-1k.tiny", tmp_path)
-    assert cell.config["n_points"] == 1000
-    assert cell.traffic["mix"][0]["half_width"] == [0.1, 0.1]
+    cell = h.resolve_cell(h.load_manifest(tmp_path), cell_name, tmp_path)
+    assert cell.config == config
+    assert cell.traffic == traffic
     assert "tiny.answered" in [m["name"] for m in cell.per_layer]
     read = h.load_reader("tiny.answered", bench / "metrics")
 

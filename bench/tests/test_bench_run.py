@@ -29,7 +29,9 @@ SEED = 2**31 + 99
 # cells at N points: the manifest's own, and the mixes kept for later cells
 MIXES = {
     "osm-10m.window-focused": ("osm-10m", mixes.window_focused),
-    "osm-10m.knn-near": ("osm-10m", lambda: mixes.KNN_NEAR),
+    "osm-10m.knn-near": ("osm-10m", mixes.knn_near),
+    "osm-25m.window-focused": (
+        "osm-25m", lambda: mixes.shortened("window-focused-25m")),
     "nycyt5.mixed": ("nycyt5", lambda: mixes.MIXED),
     "osm-10m.window-focused.closed": (
         "osm-10m", lambda: mixes.closed(mixes.window_focused())),
@@ -55,7 +57,7 @@ def small(cell_name):
 def one_run(cell, seconds=1.0, control=False):
     return run.run_cell(cell, SEED, seconds, False, jax.devices(),
                         control=control, t_start=time.monotonic(),
-                        limits={**reference.LIMITS, **mixes.KNN_LIMITS})
+                        limits=reference.LIMITS)
 
 
 @pytest.mark.parametrize("cell_name", sorted(MIXES))
@@ -136,9 +138,27 @@ def _drop_half(monkeypatch):
     monkeypatch.setattr(queries_jax, "window_query_batch_jax", half)
 
 
+def _drop_half_knn(monkeypatch):
+    from repro.core import queries_jax
+
+    real = queries_jax.knn_query_batch_jax
+
+    def half(dev, qs, k, **kw):
+        out = real(dev, qs, k, **kw)
+        res = out[0] if isinstance(out, tuple) else out
+        for i in range(len(res) // 2, len(res)):
+            res[i] = res[i][:0]  # the batch's second half answered empty
+        return out
+
+    monkeypatch.setattr(queries_jax, "knn_query_batch_jax", half)
+
+
 @pytest.mark.parametrize("cell_name,alter", [
     ("osm-10m.window-focused", _alter_windows),
     ("osm-10m.knn-near", _alter_knn),
+    ("osm-10m.knn-near", _drop_half_knn),
+    ("osm-25m.window-focused", _alter_windows),
+    ("osm-25m.window-focused", _drop_half),
     ("nycyt5.mixed", _alter_windows),
     ("nycyt5.mixed", _alter_knn),
     ("osm-10m.window-focused", _raise_windows),
@@ -166,18 +186,29 @@ def test_a_failing_scan_is_not_correct_even_with_nothing_sampled(
     assert not result["correct"]
 
 
-def test_a_knn_cell_is_refused_until_its_limit_is_measured(tmp_path):
+@pytest.mark.parametrize("limited", [False, True],
+                         ids=["no-limit-refused", "knn-resolves"])
+def test_a_cell_resolves_only_where_its_kinds_have_limits(
+        tmp_path, monkeypatch, limited):
+    """A k-NN cell resolves now that ``knn_gap`` has its limit; a mix
+    whose kind is compared by a number without a limit is still refused."""
     import shutil
 
+    if not limited:
+        monkeypatch.delitem(reference.LIMITS, "knn_gap")
     shutil.copytree(ROOT / "bench" / "configs", tmp_path / "bench" / "configs")
     (tmp_path / "bench" / "traffic").mkdir()
     (tmp_path / "bench" / "traffic" / "knn.json").write_text(
-        json.dumps(mixes.KNN_NEAR))
+        json.dumps(mixes.knn_near()))
     manifest = h.load_manifest(ROOT)
     manifest["workloads"] = [{"name": "osm-10m.knn", "config": "osm-10m",
                               "traffic": "knn", "chips": 1, "why": "test"}]
-    with pytest.raises(h.SetupError, match="knn_gap"):
-        h.resolve_cell(manifest, "osm-10m.knn", tmp_path)
+    if limited:
+        cell = h.resolve_cell(manifest, "osm-10m.knn", tmp_path)
+        assert cell.traffic["mix"][0]["kind"] == "knn"
+    else:
+        with pytest.raises(h.SetupError, match="knn_gap"):
+            h.resolve_cell(manifest, "osm-10m.knn", tmp_path)
 
 
 def test_points_are_float32_exact_and_fixed():

@@ -57,8 +57,12 @@ def _read(name, ctx):
 
 def test_the_new_metrics_are_in_the_manifest():
     m = {x["name"]: x for x in h.load_manifest(ROOT)["per_layer"]}
+    windows = {"osm-10m.window-focused"}
     for name in NEW:
-        assert m[name]["workloads"] == ["osm-10m.window-focused"]
+        # the frontend's counters read any cell; the query spans windows
+        cells = windows | ({"osm-10m.knn-near"}
+                           if name.startswith("frontend.") else set())
+        assert set(m[name]["workloads"]) == cells
         assert m[name]["moves"] == "p50_ms"
 
 

@@ -18,6 +18,14 @@ def bytes_pair_window_ids(p: int, s: int, d: int) -> int:
     return p * per_pair
 
 
+def bytes_pair_dist2(p: int, s: int, d: int) -> int:
+    """Fused (query, leaf) candidate-distance scan: per pair one leaf
+    block of points + its count + one query point in, one row of squared
+    distances out."""
+    per_pair = s * d * 4 + 4 + d * 4 + s * 4
+    return p * per_pair
+
+
 def window_pairs_each(leaf_lo: np.ndarray, leaf_hi: np.ndarray,
                       los: np.ndarray, his: np.ndarray,
                       block: int = 64) -> np.ndarray:
@@ -35,3 +43,20 @@ def window_pairs(leaf_lo: np.ndarray, leaf_hi: np.ndarray, los: np.ndarray,
                  his: np.ndarray, block: int = 64) -> int:
     """All the windows' (window, leaf) pairs."""
     return int(window_pairs_each(leaf_lo, leaf_hi, los, his, block).sum())
+
+
+def knn_leaves_each(leaf_lo: np.ndarray, leaf_hi: np.ndarray,
+                    qs: np.ndarray, kth_d2: np.ndarray,
+                    block: int = 64) -> np.ndarray:
+    """Each query's leaves whose box lies within its k-th nearest squared
+    distance ``kth_d2`` (squared box mindist at most that): the leaves an
+    exact k-NN has to read, whatever budget it scans them by."""
+    lo = np.asarray(leaf_lo, dtype=np.float64)[None]
+    hi = np.asarray(leaf_hi, dtype=np.float64)[None]
+    out = [np.zeros(0, dtype=np.int64)]
+    for a in range(0, len(qs), block):
+        q = np.asarray(qs[a:a + block], dtype=np.float64)[:, None, :]
+        g = np.maximum(lo - q, 0.0) + np.maximum(q - hi, 0.0)
+        mind = np.sum(g * g, axis=2)
+        out.append((mind <= kth_d2[a:a + block, None]).sum(axis=1))
+    return np.concatenate(out)
