@@ -12,7 +12,12 @@ A traffic file holds:
 - ``queue_bound``: the ``Frontend``'s admission bound;
 - ``mix``: request kinds with their ``share`` (see :func:`make_requests`);
 - ``focus``: the middle of a focused mix's hot spot, one coordinate per
-  dimension (a place the configuration's data fills);
+  dimension (a place the configuration's data fills).  A focus centre
+  may carry ``"drift": {"every_s": s, "to": "data"}``: the hot spot then
+  moves, and at the start of each ``s``-second period it jumps to a data
+  point drawn from the seed (:class:`HotSpot`); each request is centred
+  by the period its due time (open loop) or send time (closed loop)
+  falls in;
 - ``check``: how many of the window's answers the run compares with the
   reference, drawn from the seed;
 - ``warmup_seconds`` and ``warmup_passes``: the length of one warm-up
@@ -22,7 +27,9 @@ Open-loop arrivals are a Poisson process conditioned on its count: exactly
 ``round(rate * seconds)`` requests, due at uniformly drawn instants, so
 every seed offers the same work in another order.  The same holds for the
 mix: each kind gets its share of the requests, in an order drawn from the
-seed.
+seed.  A drifting hot spot draws its places from a stream of its own, so
+a mix without ``drift`` draws the same requests whether or not the
+generator knows of drift.
 """
 from __future__ import annotations
 
@@ -64,18 +71,70 @@ class Requests:
         return len(self.kind)
 
 
+class HotSpot:
+    """Where a drifting focus lies over time: period ``p``, the
+    ``every_s`` seconds from ``p * every_s``, is centred on the ``p``-th
+    data point drawn from ``rng``.  The draws are made in blocks of
+    ``BLOCK`` periods as they are asked for, so a period's centre does not
+    depend on when it was first asked for."""
+
+    BLOCK = 64
+
+    def __init__(self, drift: dict, points: np.ndarray,
+                 rng: np.random.Generator):
+        if drift.get("to") != "data":
+            raise ValueError(f"unknown drift target {drift.get('to')!r}")
+        self.every_s = float(drift["every_s"])
+        if not self.every_s > 0:
+            raise ValueError(f"drift every_s must be positive: {drift!r}")
+        self.points, self.rng = points, rng
+        self.rows = np.zeros(0, dtype=np.int64)
+
+    def at(self, t) -> np.ndarray:
+        """The centre at each time ``t``, in seconds from the start."""
+        p = (np.maximum(np.asarray(t, dtype=np.float64), 0.0)
+             // self.every_s).astype(np.int64)
+        while p.max(initial=-1) >= len(self.rows):
+            self.rows = np.concatenate([self.rows, self.rng.integers(
+                len(self.points), size=self.BLOCK)])
+        return self.points[self.rows[p]]
+
+
+def _drifts(spec: dict) -> bool:
+    return "drift" in spec.get("centre", {})
+
+
+def hotspots(traffic: dict, points: np.ndarray,
+             rng: np.random.Generator) -> dict:
+    """A :class:`HotSpot` for each mix entry whose focus drifts, by the
+    entry's index, each on a child stream of ``rng``.  A mix without drift
+    gets none, and nothing is drawn."""
+    drifting = [i for i, m in enumerate(traffic["mix"]) if _drifts(m)]
+    if not drifting:
+        return {}
+    if rng is None:
+        raise ValueError("a drifting mix needs a stream for its hot spots")
+    return {i: HotSpot(traffic["mix"][i]["centre"]["drift"], points, child)
+            for i, child in zip(drifting, rng.spawn(len(drifting)))}
+
+
 def _centres(spec: dict, n: int, traffic: dict, points: np.ndarray,
              rng: np.random.Generator) -> np.ndarray:
     """``centre: {"from": "focus", "side": s}`` draws uniformly from the
-    s-wide square around ``traffic["focus"]``; ``{"from": "data"}`` takes
-    data points, moved by up to ``jitter`` in each dimension."""
+    s-wide square around ``traffic["focus"]`` (around the origin where the
+    focus drifts: the hot spot is added once a request's time is known);
+    ``{"from": "data"}`` takes data points, moved by up to ``jitter`` in
+    each dimension."""
     src = spec["centre"]
     d = points.shape[1]
     if src["from"] == "focus":
         side = float(src["side"])
-        focus = np.asarray(traffic["focus"], dtype=np.float64)
+        focus = np.zeros(d) if _drifts(spec) else np.asarray(
+            traffic["focus"], dtype=np.float64)
         return focus - side / 2 + rng.random((n, d)) * side
     if src["from"] == "data":
+        if _drifts(spec):
+            raise ValueError("only a focus centre drifts")
         c = points[rng.integers(len(points), size=n)]
         j = float(src.get("jitter", 0.0))
         if j:
@@ -84,40 +143,80 @@ def _centres(spec: dict, n: int, traffic: dict, points: np.ndarray,
     raise ValueError(f"unknown centre {src!r}")
 
 
-def make_requests(traffic: dict, points: np.ndarray, n: int,
-                  rng: np.random.Generator) -> Requests:
-    """``n`` requests of the mix.  A window entry gives ``half_width``, one
-    per dimension; ``null`` leaves that dimension open (the whole [0, 1]
-    range).  A k-NN entry gives ``k``.  Coordinates are float32-exact."""
+@dataclasses.dataclass
+class Draws:
+    """A block of requests as drawn from the seed, before they are placed:
+    each one's mix entry and its centre.  For an entry whose focus drifts
+    the centre is an offset from the hot spot, which is known only once
+    the request's time is."""
+
+    mix: list
+    entry: np.ndarray
+    centre: np.ndarray
+
+    def place(self, idx=None, times=None, spots=None) -> Requests:
+        """Requests ``idx`` (all by default), those of a drifting entry
+        centred by where ``spots`` (from :func:`hotspots`) lie at their
+        ``times``."""
+        idx = np.arange(len(self.entry)) if idx is None else np.asarray(idx)
+        entry, c = self.entry[idx], self.centre[idx].copy()
+        n, d = c.shape
+        kind = np.zeros(n, dtype=np.int8)
+        lo = np.zeros((n, d))
+        hi = np.ones((n, d))
+        k = np.zeros(n, dtype=np.int64)
+        for i, spec in enumerate(self.mix):
+            sel = np.flatnonzero(entry == i)
+            if _drifts(spec) and len(sel):
+                if times is None or spots is None:
+                    raise ValueError("a drifting focus needs the requests' "
+                                     "times and its hot spots")
+                c[sel] += spots[i].at(np.asarray(times)[sel])
+            ci = np.clip(c[sel], 0.0, 1.0)
+            if spec["kind"] == "window":
+                hw = spec["half_width"]
+                if len(hw) != d:
+                    raise ValueError(f"half_width has {len(hw)} entries for "
+                                     f"{d}-dimensional data")
+                for j, h in enumerate(hw):
+                    if h is not None:
+                        lo[sel, j] = np.clip(ci[:, j] - h, 0.0, 1.0)
+                        hi[sel, j] = np.clip(ci[:, j] + h, 0.0, 1.0)
+            elif spec["kind"] == "knn":
+                kind[sel] = 1
+                lo[sel] = ci
+                k[sel] = int(spec["k"])
+            else:
+                raise ValueError(f"unknown request kind {spec['kind']!r}")
+        return Requests(kind, f32_exact(lo), f32_exact(hi), k)
+
+
+def draw_requests(traffic: dict, points: np.ndarray, n: int,
+                  rng: np.random.Generator) -> Draws:
+    """``n`` requests of the mix, drawn but not yet placed (see
+    :func:`make_requests`)."""
     mix = traffic["mix"]
     d = points.shape[1]
     shares = np.array([float(m["share"]) for m in mix])
     counts = np.floor(shares / shares.sum() * n).astype(int)
     counts[np.argmax(shares)] += n - counts.sum()
     which = rng.permutation(np.repeat(np.arange(len(mix)), counts))
-    kind = np.zeros(n, dtype=np.int8)
-    lo = np.zeros((n, d))
-    hi = np.ones((n, d))
-    k = np.zeros(n, dtype=np.int64)
+    centre = np.zeros((n, d))
     for i, spec in enumerate(mix):
         sel = np.flatnonzero(which == i)
-        c = np.clip(_centres(spec, len(sel), traffic, points, rng), 0.0, 1.0)
-        if spec["kind"] == "window":
-            hw = spec["half_width"]
-            if len(hw) != d:
-                raise ValueError(f"half_width has {len(hw)} entries for "
-                                 f"{d}-dimensional data")
-            for j, h in enumerate(hw):
-                if h is not None:
-                    lo[sel, j] = np.clip(c[:, j] - h, 0.0, 1.0)
-                    hi[sel, j] = np.clip(c[:, j] + h, 0.0, 1.0)
-        elif spec["kind"] == "knn":
-            kind[sel] = 1
-            lo[sel] = c
-            k[sel] = int(spec["k"])
-        else:
-            raise ValueError(f"unknown request kind {spec['kind']!r}")
-    return Requests(kind, f32_exact(lo), f32_exact(hi), k)
+        centre[sel] = _centres(spec, len(sel), traffic, points, rng)
+    return Draws(mix, which, centre)
+
+
+def make_requests(traffic: dict, points: np.ndarray, n: int,
+                  rng: np.random.Generator, times=None,
+                  spots=None) -> Requests:
+    """``n`` requests of the mix.  A window entry gives ``half_width``, one
+    per dimension; ``null`` leaves that dimension open (the whole [0, 1]
+    range).  A k-NN entry gives ``k``.  Coordinates are float32-exact.
+    Where the focus drifts, request i is centred by where ``spots`` (from
+    :func:`hotspots`) lie at ``times[i]``."""
+    return draw_requests(traffic, points, n, rng).place(None, times, spots)
 
 
 def arrivals(traffic: dict, seconds: float,
@@ -129,19 +228,25 @@ def arrivals(traffic: dict, seconds: float,
 
 class RequestStream:
     """Requests made on demand, in blocks, from one stream of the seed:
-    the i-th request is the same whatever the loop asks for after it."""
+    the i-th request is the same whatever the loop asks for after it, and
+    where the focus drifts it is centred by the time it is sent."""
 
     BLOCK = 4096
 
     def __init__(self, traffic: dict, points: np.ndarray,
-                 rng: np.random.Generator):
+                 rng: np.random.Generator, spots=None):
         self.traffic, self.points, self.rng = traffic, points, rng
-        self.blocks: list[Requests] = []
+        self.spots = spots or {}
+        self.blocks: list = []  # Requests, or Draws where the focus drifts
 
-    def get(self, i: int) -> tuple[int, np.ndarray, np.ndarray, int]:
+    def get(self, i: int, t: float = 0.0
+            ) -> tuple[int, np.ndarray, np.ndarray, int]:
         b, j = divmod(i, self.BLOCK)
         while len(self.blocks) <= b:
-            self.blocks.append(make_requests(self.traffic, self.points,
-                                             self.BLOCK, self.rng))
+            block = draw_requests(self.traffic, self.points, self.BLOCK,
+                                  self.rng)
+            self.blocks.append(block if self.spots else block.place())
         r = self.blocks[b]
+        if self.spots:
+            r, j = r.place([j], [t], self.spots), 0
         return int(r.kind[j]), r.lo[j], r.hi[j], int(r.k[j])

@@ -4,17 +4,22 @@ the reference, and the numbers.
 Everything a cell needs is found by name: ``BENCHMARK.json`` names the
 cell's configuration (``bench/configs/<config>.json``), its traffic mix
 (``bench/traffic/<traffic>.json``, read by :mod:`bench.generator`) and its
-per-layer metrics (``bench/metrics/<metric>.py``, each a ``read(ctx)``).
-Adding a configuration, a mix or a metric adds files and entries; no code
+per-layer metrics (``bench/metrics/<metric>.py``, each a ``read(ctx)``);
+the configuration's ``boot`` (``"fmbi"`` where it names none) names how
+its index is booted (``bench/boot/<boot>.py``, a ``boot(config, points,
+batch_max)`` that returns the server and its build times).  Adding a
+configuration, a boot, a mix or a metric adds files and entries; no code
 here changes.
 
 Set-up, which ``setup_s`` measures from the process's start: the
-configuration's fixed data, the host FMBI bulk load,
-``DeviceQueryServer.from_index``, and a warm-up (on a seed stream apart
-from the window's): batches of the cell's own requests planned by the
-engine's buckets (:mod:`bench.warmup`), then passes of its traffic until
-one builds no new program.  The window then drives
-a started ``Frontend`` over that server for ``seconds``; open-loop
+configuration's fixed data, the boot, and a warm-up (on a seed stream
+apart from the window's): batches of the cell's own requests planned by
+the engine's buckets (:mod:`bench.warmup`), then passes of its traffic
+until one builds no new program.  A boot whose module sets
+``CHANGES_AS_IT_SERVES`` (an adaptive index refines as it serves) is
+warmed up on a second server booted alike, with the passes alone, so the
+window starts from the state the boot made.  The window then drives a
+started ``Frontend`` over the booted server for ``seconds``; open-loop
 requests are timed from when each was due, closed-loop ones from when
 their client sent them.
 """
@@ -23,6 +28,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import gc
 import heapq
 import importlib.util
 import json
@@ -69,6 +75,10 @@ def _for_cell(metrics: list, cell: str) -> list:
     return [m for m in metrics if cell in m.get("workloads", [cell])]
 
 
+def boot_name(config: dict) -> str:
+    return config.get("boot", "fmbi")
+
+
 def resolve_cell(manifest: dict, name: str,
                  root: pathlib.Path = ROOT) -> Cell:
     cells = {w["name"]: w for w in manifest["workloads"]}
@@ -77,6 +87,10 @@ def resolve_cell(manifest: dict, name: str,
     w = cells[name]
     configs = {c["name"]: c for c in manifest["configs"]}
     config = json.loads((root / configs[w["config"]]["file"]).read_text())
+    boot = boot_name(config)
+    if not (BENCH / "boot" / f"{boot}.py").is_file():
+        raise SetupError(f"{name}: no boot {boot!r} "
+                         f"(bench/boot/{boot}.py)")
     traffic = gen.load_traffic(w["traffic"], root / "bench" / "traffic")
     from bench import reference
 
@@ -90,14 +104,23 @@ def resolve_cell(manifest: dict, name: str,
                 _for_cell(manifest["per_layer"], name))
 
 
-def load_reader(metric: str, metrics_dir: pathlib.Path = BENCH / "metrics"):
-    """The ``read(ctx)`` of ``bench/metrics/<metric>.py``."""
-    path = metrics_dir / f"{metric}.py"
+def _load(path: pathlib.Path, prefix: str):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str, metrics_dir: pathlib.Path = BENCH / "metrics"):
+    """The ``read(ctx)`` of ``bench/metrics/<metric>.py``."""
+    return _load(metrics_dir / f"{metric}.py", "bench_metric_").read
+
+
+def load_boot(name: str, boot_dir: pathlib.Path = BENCH / "boot"):
+    """The module ``bench/boot/<name>.py``: its ``boot`` and its
+    ``CHANGES_AS_IT_SERVES``."""
+    return _load(boot_dir / f"{name}.py", "bench_boot_")
 
 
 # -- the chip and the compile cache ------------------------------------------
@@ -203,24 +226,15 @@ def buffer_pages(config: dict, points: np.ndarray) -> int:
 
 
 def build_and_boot(config: dict, points: np.ndarray):
-    """Host FMBI bulk load, then ``DeviceQueryServer.from_index`` until its
-    table is on the device.  Returns the server and both times."""
-    import jax
-    from jax.profiler import TraceAnnotation
+    """The configuration's boot, until its table is on the device.
+    Returns the server and the build times (``bulk_load_s``,
+    ``upload_s``)."""
+    return load_boot(boot_name(config)).boot(config, points, BATCH_MAX)
 
-    from repro.core import PageStore, bulk_load
-    from repro.serve.engine import DeviceQueryServer
 
-    m = buffer_pages(config, points)
-    t0 = time.perf_counter()
-    with TraceAnnotation("build.bulk_load"):
-        idx = bulk_load(points, m, PageStore(m))
-    t1 = time.perf_counter()
-    with TraceAnnotation("build.boot"):
-        srv = DeviceQueryServer.from_index(idx, microbatch=BATCH_MAX)
-        jax.block_until_ready(srv.dev)
-    t2 = time.perf_counter()
-    return srv, {"bulk_load_s": t1 - t0, "upload_s": t2 - t1}
+def server_stats(server) -> dict:
+    """The server's counters (``DeviceQueryStats``) as they stand."""
+    return dataclasses.asdict(server.stats)
 
 
 class TimedServer:
@@ -360,7 +374,7 @@ def drive_open(fe, reqs: gen.Requests, offsets: np.ndarray, rec: Records,
 
 
 def drive_closed(fe, stream: gen.RequestStream, outstanding: int,
-                 rec: Records, t_end: float) -> None:
+                 rec: Records, t0: float, t_end: float) -> None:
     """Keep ``outstanding`` requests in flight until ``t_end``: each answer
     sends the next request of the stream at once."""
     flight = collections.deque()
@@ -368,7 +382,7 @@ def drive_closed(fe, stream: gen.RequestStream, outstanding: int,
 
     def send():
         nonlocal i
-        kind, lo, hi, k = stream.get(i)
+        kind, lo, hi, k = stream.get(i, time.monotonic() - t0)
         with _span("generator.submit"):
             req = _submit(fe, kind, lo, hi, k)
         rec.add(i, kind, lo, hi, k, req.t_submit, req)
@@ -388,11 +402,13 @@ def drive_closed(fe, stream: gen.RequestStream, outstanding: int,
 
 def run_window(server, traffic: dict, points: np.ndarray, seconds: float,
                rng_req, rng_arr, sample: Sample | None = None,
-               spans: bool = True):
-    """Drive a fresh, started ``Frontend`` over ``server`` for ``seconds``.
-    Returns the records, the Frontend's stats and the window's bounds."""
+               spans: bool = True, rng_drift=None):
+    """Drive a fresh, started ``Frontend`` over ``server`` for ``seconds``;
+    a drifting hot spot draws its places from ``rng_drift``.  Returns the
+    records, the Frontend's stats and the window's bounds."""
     from repro.serve.frontend import Frontend
 
+    spots = gen.hotspots(traffic, points, rng_drift)
     fe = Frontend(server, queue_bound=int(traffic["queue_bound"]),
                   batch_max=BATCH_MAX).start()
     rec = Records(sample)
@@ -402,14 +418,15 @@ def run_window(server, traffic: dict, points: np.ndarray, seconds: float,
         with _span("bench.window") if spans else contextlib.nullcontext():
             if traffic["loop"] == "open":
                 offsets = gen.arrivals(traffic, seconds, rng_arr)
-                reqs = gen.make_requests(traffic, points, len(offsets), rng_req)
+                reqs = gen.make_requests(traffic, points, len(offsets),
+                                         rng_req, offsets, spots)
                 drive_open(fe, reqs, offsets, rec, t0)
             elif traffic["loop"] == "closed":
-                stream = gen.RequestStream(traffic, points, rng_req)
+                stream = gen.RequestStream(traffic, points, rng_req, spots)
                 while time.monotonic() < t0:
                     time.sleep(t0 - time.monotonic())
                 drive_closed(fe, stream, int(traffic["outstanding"]), rec,
-                             t_end)
+                             t0, t_end)
             else:
                 raise ValueError(f"unknown loop {traffic['loop']!r}")
             rec.wait_all(t_end + GRACE_S)
@@ -419,28 +436,44 @@ def run_window(server, traffic: dict, points: np.ndarray, seconds: float,
 
 
 def warm_up(server, traffic: dict, points: np.ndarray, seed: int,
-            clock: CompileClock, seconds: float) -> dict:
+            clock: CompileClock, seconds: float, config: dict) -> dict:
     """Build every program the traffic reaches before the window: the
     batches :mod:`bench.warmup` plans by the engine's buckets, then passes
     of the cell's own traffic through a ``Frontend`` until one builds
-    nothing new (at most ``warmup_passes``)."""
+    nothing new (at most ``warmup_passes``).
+
+    Where the configuration's boot changes its server as it serves, no
+    request reaches ``server``: a second server, booted alike from the
+    same points, takes the passes and is dropped.  The plan is left out
+    there, since it is built from the leaf boxes of a table that the
+    passes themselves refine."""
     from bench import warmup
 
-    dev = server.dev
-    planned = warmup.run(server, traffic, points,
-                         gen.rng_for(seed, gen.STREAM_WARMUP, 0),
-                         np.asarray(dev.leaf_lo)[: dev.n_leaves],
-                         np.asarray(dev.leaf_hi)[: dev.n_leaves], BATCH_MAX)
+    boot = load_boot(boot_name(config))
+    if boot.CHANGES_AS_IT_SERVES:
+        server, _ = boot.boot(config, points, BATCH_MAX)
+        planned = {"planned_batches": 0}
+    else:
+        dev = server.dev
+        planned = warmup.run(server, traffic, points,
+                             gen.rng_for(seed, gen.STREAM_WARMUP, 0),
+                             np.asarray(dev.leaf_lo)[: dev.n_leaves],
+                             np.asarray(dev.leaf_hi)[: dev.n_leaves],
+                             BATCH_MAX)
     planned_builds = clock.builds
     passes, before = 0, clock.builds
     for p in range(int(traffic["warmup_passes"])):
         before = clock.builds
         run_window(server, traffic, points, seconds,
                    gen.rng_for(seed, gen.STREAM_WARMUP, 1, p),
-                   gen.rng_for(seed, gen.STREAM_WARMUP, 2, p), spans=False)
+                   gen.rng_for(seed, gen.STREAM_WARMUP, 2, p), spans=False,
+                   rng_drift=gen.rng_for(seed, gen.STREAM_WARMUP, 3, p))
         passes += 1
         if clock.builds == before:
             break
+    if boot.CHANGES_AS_IT_SERVES:
+        del server
+        gc.collect()  # the second server's tables leave the device now
     return {**planned, "planned_builds": planned_builds, "passes": passes,
             "pass_builds": clock.builds - planned_builds,
             "last_pass_builds": clock.builds - before}
@@ -496,6 +529,17 @@ class Context:
     leaf_size: int
     device_kind: str
     reference: object = None
+    # the server's counters (``server_stats``) as the window started, and
+    # once its last answer came
+    stats_start: dict = dataclasses.field(default_factory=dict)
+    stats_end: dict = dataclasses.field(default_factory=dict)
+
+    def stat_change(self, name: str):
+        """How much the server's counter ``name`` grew over the window;
+        None where the server has no such counter."""
+        if name not in self.stats_start or name not in self.stats_end:
+            return None
+        return self.stats_end[name] - self.stats_start[name]
 
     def calls(self, kind: str) -> int:
         return sum(1 for c in self.engine_calls if c[0] == kind)

@@ -60,7 +60,7 @@ def run_cell(cell: h.Cell, seed: int, seconds: float, trace: bool,
             f"leaves={server.dev.n_leaves} leaf_size={server.dev.leaf_size} "
             f"bulk_load_s={build['bulk_load_s']} upload_s={build['upload_s']}")
         warm = h.warm_up(server, cell.traffic, points, seed, clock,
-                         float(cell.traffic["warmup_seconds"]))
+                         float(cell.traffic["warmup_seconds"]), cell.config)
         setup = clock.snapshot()
         say(f"[setup] planned_batches={warm['planned_batches']} "
             f"planned_builds={warm['planned_builds']} "
@@ -80,15 +80,18 @@ def run_cell(cell: h.Cell, seed: int, seconds: float, trace: bool,
             opts.python_tracer_level = 0
             opts.host_tracer_level = 1
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        stats_start = h.server_stats(server)
         try:
             rec, fe_stats, window = h.run_window(
                 timed, cell.traffic, points, seconds,
                 gen.rng_for(seed, gen.STREAM_WINDOW, 0),
                 gen.rng_for(seed, gen.STREAM_WINDOW, 1),
-                h.sample_for(cell.traffic, seed))
+                h.sample_for(cell.traffic, seed),
+                rng_drift=gen.rng_for(seed, gen.STREAM_WINDOW, 2))
         finally:
             if trace:
                 jax.profiler.stop_trace()
+        stats_end = h.server_stats(server)
         window_builds = clock.builds - setup["builds"]
         window_cache = (clock.hits - setup["cache_hits"],
                         clock.misses - setup["cache_misses"])
@@ -112,6 +115,9 @@ def run_cell(cell: h.Cell, seed: int, seconds: float, trace: bool,
         f"cache_hits={window_cache[0]} cache_misses={window_cache[1]} "
         f"{clock.by_function_text(setup_by_function)}")
     say("[window] slowest " + h.slowest_text(rec, window))
+    say("[window] server_counters " + " ".join(
+        f"{k}:{stats_start[k]}->{v}" for k, v in stats_end.items()
+        if v or stats_start[k]))
 
     # the reference runs once the window has closed, the peak has been
     # read and the server is gone
@@ -138,7 +144,7 @@ def run_cell(cell: h.Cell, seed: int, seconds: float, trace: bool,
     else:
         ctx = h.Context(cell, seconds, rec, window, fe_stats, calls, build,
                         window_builds, summary, leaf_lo, leaf_hi, leaf_size,
-                        devices[0].device_kind, ref)
+                        devices[0].device_kind, ref, stats_start, stats_end)
         result["metrics"] = {}
         for m in cell.per_layer:
             value = h.load_reader(m["name"])(ctx)

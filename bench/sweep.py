@@ -5,12 +5,15 @@ which the backlog does not grow over the window.
     python3 bench/sweep.py --workload <cell> --seed <n> \\
         --rates 20,30,40,50 [--requests 1000]
 
-One process: set-up as ``bench/run.py`` makes it (data, bulk load, boot,
-the warm-up by the engine's buckets), then one window per rate,
-ascending, each at that rate and otherwise as the traffic file says, and
-long enough to offer ``--requests`` requests.  A rate is judged only on a
-window in which no program was built; a window that built one is run
-again.  A rate is sustained when
+One process: set-up as ``bench/run.py`` makes it (data, boot, warm-up),
+then one window per rate, ascending, each at that rate and otherwise as
+the traffic file says, and long enough to offer ``--requests`` requests.
+A rate is judged only on a window in which no program was built; a window
+that built one is run again.  Where the boot changes its server as it
+serves, each window is served by a server freshly booted alike, as a
+cell's window is, and is judged as it comes, with the programs it built:
+its table's shapes grow with what it grafts, so a second window would
+build again.  A rate is sustained when
 
 - every request is answered ``ok``;
 - at least 98% of the requests due in the window were answered inside
@@ -91,11 +94,12 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     h.enable_compile_cache(ROOT)
+    adaptive = h.load_boot(h.boot_name(cell.config)).CHANGES_AS_IT_SERVES
     with h.CompileClock() as clock:
         points = make_points(cell.config)
         server, build = h.build_and_boot(cell.config, points)
         h.warm_up(server, cell.traffic, points, args.seed, clock,
-                  float(cell.traffic["warmup_seconds"]))
+                  float(cell.traffic["warmup_seconds"]), cell.config)
         print(json.dumps({"cell": cell.name, "build": build,
                           "setup_s": time.monotonic() - T_START,
                           "device": devices[0].device_kind}), flush=True)
@@ -104,20 +108,26 @@ def main(argv=None) -> int:
             traffic = dict(cell.traffic, rate=rate)
             seconds = args.requests / rate
             for attempt in range(2):
-                builds = clock.builds
+                if adaptive:
+                    server, _ = h.build_and_boot(cell.config, points)
+                builds, counters = clock.builds, h.server_stats(server)
                 rec, stats, window = h.run_window(
                     server, traffic, points, seconds,
                     gen.rng_for(args.seed, gen.STREAM_WINDOW, 0, i, attempt),
-                    gen.rng_for(args.seed, gen.STREAM_WINDOW, 1, i, attempt))
+                    gen.rng_for(args.seed, gen.STREAM_WINDOW, 1, i, attempt),
+                    rng_drift=gen.rng_for(args.seed, gen.STREAM_WINDOW, 2, i,
+                                          attempt))
                 row = {"rate": rate, "seconds": seconds,
                        **judge(rec, window, seconds),
                        "batches": stats.batches,
                        "batch_mean": stats.completed / max(stats.batches, 1),
-                       "builds": clock.builds - builds}
+                       "builds": clock.builds - builds,
+                       "server": {k: v - counters[k] for k, v in
+                                  h.server_stats(server).items()}}
                 print(json.dumps(row), flush=True)
-                if not row["builds"]:
+                if adaptive or not row["builds"]:
                     break
-            if row["builds"]:
+            if row["builds"] and not adaptive:
                 row["sustained"] = False  # no window of this rate without a build
             if row["sustained"]:
                 knee, misses = rate, 0

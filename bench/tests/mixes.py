@@ -1,7 +1,7 @@
 """The mixes as the tests drive them on the CPU: the cells' own traffic
 files, shortened, and mixes that no cell runs yet (a 5-D mix of windows
-and k-NN, and a closed loop).  A cell that sends them adds its own traffic
-file with its measured rate."""
+and k-NN, a closed loop, and a hot spot that drifts).  A cell that sends
+them adds its own traffic file with its measured rate."""
 import copy
 
 from bench import generator as gen
@@ -33,3 +33,13 @@ def window_focused() -> dict:
 
 def knn_near() -> dict:
     return shortened("knn-near")
+
+
+def drifting(traffic: dict, every_s: float) -> dict:
+    """The mix with its focused windows' hot spot jumping to a data point
+    every ``every_s`` seconds."""
+    t = copy.deepcopy(traffic)
+    for m in t["mix"]:
+        if m["centre"]["from"] == "focus":
+            m["centre"]["drift"] = {"every_s": every_s, "to": "data"}
+    return t

@@ -1,5 +1,6 @@
-"""A configuration, a traffic mix and a per-layer metric are found by
-name: adding them as files and manifest entries is all a new cell needs."""
+"""A configuration, its boot, a traffic mix and a per-layer metric are
+found by name: adding them as files and manifest entries is all a new cell
+needs."""
 import json
 import pathlib
 import shutil
@@ -38,7 +39,16 @@ NEW_CELLS = {
         {"generator": "nycyt_like", "n_points": 1000, "dim": 5,
          "data_seed": 0, "buffer_fraction": 0.05},
         mixes.MIXED),
+    # the adaptive server: AMBI's cold start under a hot spot that moves
+    "osm-1k.ambi-drift": (
+        {"generator": "osm_like", "n_points": 1000, "dim": 2,
+         "data_seed": 0, "buffer_fraction": 0.05, "boot": "ambi"},
+        dict(mixes.drifting(mixes.window_focused(), 0.2), rate=20.0,
+             warmup_seconds=0.2, check=64)),
 }
+# a reader of the server's counters, as a cell of the adaptive boot adds
+GRAFTS = "def read(ctx):\n    g = ctx.stat_change(\"grafts\")\n" \
+         "    return None if g is None else g / ctx.seconds\n"
 
 
 @pytest.mark.parametrize("cell_name", sorted(NEW_CELLS))
@@ -53,6 +63,7 @@ def test_new_files_are_found_by_name(tmp_path, cell_name):
         json.dumps(traffic))
     (bench / "metrics" / "tiny.answered.py").write_text(
         "def read(ctx):\n    return float(sum(ctx.records.ok))\n")
+    (bench / "metrics" / "tiny.grafts_per_s.py").write_text(GRAFTS)
     # ... and entries in the manifest
     manifest = json.loads((tmp_path / "BENCHMARK.json").read_text())
     manifest["configs"].append({"name": config_name, "source": "x",
@@ -64,6 +75,11 @@ def test_new_files_are_found_by_name(tmp_path, cell_name):
     manifest["per_layer"].append({"name": "tiny.answered", "unit": "count",
                                   "better": "higher", "source": "host_clock",
                                   "layer": "test", "moves": "qps",
+                                  "workloads": [cell_name]})
+    manifest["per_layer"].append({"name": "tiny.grafts_per_s",
+                                  "unit": "grafts/s", "better": "higher",
+                                  "source": "program_counter",
+                                  "layer": "test", "moves": "p50_ms",
                                   "workloads": [cell_name]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
 
@@ -78,10 +94,46 @@ def test_new_files_are_found_by_name(tmp_path, cell_name):
             ok = [True, False, True]
 
     assert read(Ctx) == 2.0
+    grafts = h.load_reader("tiny.grafts_per_s", bench / "metrics")
+    assert grafts(_ctx({"grafts": 2}, {"grafts": 12})) == 5.0
+    assert grafts(_ctx({}, {})) is None
+    if h.boot_name(config) == "ambi":
+        import jax
+
+        from bench import run
+
+        result, numbers = run.run_cell(cell, 2**31 + 5, 0.6, False,
+                                       jax.devices())
+        assert result["correct"] and result["attempted"] > 0, numbers
     # no file that was there before changed
     for rel, data in before.items():
         if rel.name != "BENCHMARK.json":
             assert (tmp_path / rel).read_bytes() == data, rel
+
+
+def _ctx(stats_start, stats_end):
+    return h.Context(cell=None, seconds=2.0, records=None, window=(0, 2),
+                     frontend=None, engine_calls=[], build={},
+                     compiles_in_window=0, trace=None, leaf_lo=None,
+                     leaf_hi=None, leaf_size=0, device_kind="",
+                     stats_start=stats_start, stats_end=stats_end)
+
+
+def test_an_unknown_boot_is_refused_before_any_run(tmp_path):
+    _checkout(tmp_path)
+    config = dict(NEW_CELLS["osm-1k.ambi-drift"][0], boot="nowhere")
+    (tmp_path / "bench" / "configs" / "osm-1k.json").write_text(
+        json.dumps(config))
+    manifest = h.load_manifest(tmp_path)
+    manifest["configs"].append({"name": "osm-1k", "source": "x",
+                                "file": "bench/configs/osm-1k.json",
+                                "reduced": [], "why": "test"})
+    manifest["workloads"].append({"name": "osm-1k.window-focused",
+                                  "config": "osm-1k",
+                                  "traffic": "window-focused", "chips": 1,
+                                  "why": "test"})
+    with pytest.raises(h.SetupError, match="no boot 'nowhere'"):
+        h.resolve_cell(manifest, "osm-1k.window-focused", tmp_path)
 
 
 def test_every_cell_of_the_manifest_resolves():

@@ -35,9 +35,18 @@ MIXES = {
     "nycyt5.mixed": ("nycyt5", lambda: mixes.MIXED),
     "osm-10m.window-focused.closed": (
         "osm-10m", lambda: mixes.closed(mixes.window_focused())),
+    # the hot spot jumping twice in a one-second window, over the static
+    # FMBI index and over the AMBI boot
+    "osm-10m.window-drift": (
+        "osm-10m", lambda: mixes.drifting(mixes.window_focused(),
+                                          DRIFT_EVERY_S)),
+    "osm-ambi.window-drift": (
+        "osm-ambi", lambda: mixes.drifting(mixes.window_focused(),
+                                           DRIFT_EVERY_S)),
 }
 NYCYT5 = {"generator": "nycyt_like", "dim": 5, "data_seed": 0,
           "buffer_fraction": 0.05}
+DRIFT_EVERY_S = 0.4
 
 
 def small(cell_name):
@@ -46,6 +55,9 @@ def small(cell_name):
     manifest = h.load_manifest(ROOT)
     if config_name == "nycyt5":
         config = dict(NYCYT5)
+    elif config_name == "osm-ambi":
+        config = dict(json.loads((ROOT / "bench" / "configs" /
+                                  "osm-10m.json").read_text()), boot="ambi")
     else:
         config = json.loads((ROOT / "bench" / "configs" /
                              f"{config_name}.json").read_text())
@@ -163,6 +175,8 @@ def _drop_half_knn(monkeypatch):
     ("nycyt5.mixed", _alter_knn),
     ("osm-10m.window-focused", _raise_windows),
     ("osm-10m.window-focused", _drop_half),
+    ("osm-ambi.window-drift", _alter_windows),
+    ("osm-ambi.window-drift", _drop_half),
 ])
 def test_an_answer_altered_where_it_is_made_is_not_correct(
         monkeypatch, cell_name, alter):
@@ -171,6 +185,41 @@ def test_an_answer_altered_where_it_is_made_is_not_correct(
     cell.traffic["check"] = 10**6  # compare every answer
     result, numbers = one_run(cell)
     assert not result["correct"], numbers
+
+
+def test_an_adaptive_boot_serves_the_window_from_its_cold_start(
+        monkeypatch):
+    """The AMBI boot: no warm-up request reaches the server the window
+    serves (its counters are 0 as the window starts), a second server
+    takes the warm-up, and in the window queries reach cold space and
+    are grafted while the hot spot moves."""
+    calls = []
+    real = h.run_window
+
+    def spy(server, traffic, points, seconds, *a, **kw):
+        inner = getattr(server, "_server", server)
+        before = h.server_stats(inner)
+        out = real(server, traffic, points, seconds, *a, **kw)
+        calls.append((inner, before, h.server_stats(inner), out))
+        return out
+
+    monkeypatch.setattr(h, "run_window", spy)
+    result, numbers = one_run(small("osm-ambi.window-drift"))
+    assert result["correct"], numbers
+    *warm, (served, start, end, (rec, _, (t0, _))) = calls
+    assert warm and all(w[0] is not served for w in warm)
+    assert warm[-1][2]["queries"] > 0
+    assert all(v == 0 for k, v in start.items() if k != "shards"), start
+    assert end["cold_queries"] > 0 and end["grafts"] > 0
+    # the window's requests follow at least two places of the hot spot
+    cell = small("osm-ambi.window-drift")
+    spot = gen.hotspots(cell.traffic, make_points(cell.config),
+                        gen.rng_for(SEED, gen.STREAM_WINDOW, 2))[0]
+    due = np.asarray(rec.due) - t0
+    centres = spot.at(due)
+    assert len({tuple(c) for c in centres}) >= 2
+    mid = np.array([(lo + hi) / 2 for lo, hi, _ in rec.payload])
+    assert np.all(np.abs(mid - centres) <= 0.03 + 1e-6)
 
 
 def test_a_failing_scan_is_not_correct_even_with_nothing_sampled(

@@ -307,10 +307,20 @@ def knn_plan(need: np.ndarray, batch_max: int) -> list:
     return batches
 
 
+def pool_times(spots: dict, n: int) -> np.ndarray:
+    """Times that put each of ``n`` requests in a period of its own, so
+    that a pool of a drifting mix sees as many places of its hot spots
+    (:func:`bench.generator.hotspots`) as it has requests."""
+    return np.arange(n) * max((s.every_s for s in spots.values()),
+                              default=0.0)
+
+
 def run(server, traffic: dict, points: np.ndarray, rng: np.random.Generator,
         leaf_lo: np.ndarray, leaf_hi: np.ndarray, batch_max: int) -> dict:
     """Serve the plan's batches of the cell's window and k-NN requests."""
-    reqs = gen.make_requests(traffic, points, POOL, rng)
+    spots = gen.hotspots(traffic, points, rng)
+    reqs = gen.make_requests(traffic, points, POOL, rng,
+                             pool_times(spots, POOL), spots)
     win = np.flatnonzero(reqs.kind == 0)
     batches = []
     if len(win):
@@ -324,7 +334,8 @@ def run(server, traffic: dict, points: np.ndarray, rng: np.random.Generator,
     if np.any(reqs.kind == 1):
         from bench import reference
 
-        knn = gen.make_requests(traffic, points, KNN_POOL, rng)
+        knn = gen.make_requests(traffic, points, KNN_POOL, rng,
+                                pool_times(spots, KNN_POOL), spots)
         ref = reference.BruteForce(points)
         n_leaves = len(leaf_lo)
         for k in sorted(set(knn.k[knn.kind == 1].tolist())):
